@@ -612,7 +612,6 @@ def make_sharded_async_round_fn(model_cfg: ModelConfig,
     :class:`DeadlineController` can retune it every round without
     recompiling."""
     from contextlib import nullcontext
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     from repro import sharding as shardlib
     from repro.core.round import _linear_shard_index, abstract_state
@@ -656,12 +655,12 @@ def make_sharded_async_round_fn(model_cfg: ModelConfig,
                 train_cfg=train_cfg, schedule=schedule, impl=impl,
                 shard_ctx=ctx)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(st_specs, astate_specs, client_spec, rep, rep, rep, rep,
                   rep),
         out_specs=(st_specs, astate_specs, rep),
-        check_rep=False, auto=frozenset(auto))
+        check_vma=False, axis_names=frozenset(dp))
     # donate state + astate (default on): the sharded stacks, optimizer
     # slots and the stale-update buffer alias their outputs — one copy
     # live at peak.  place_state/place_astate device_put copies, so

@@ -914,7 +914,6 @@ def make_sharded_round_fn(model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
     (tests/test_sharded_round.py; the psum of per-shard partial sums
     reorders the client reduction)."""
     from contextlib import nullcontext
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     from repro import sharding as shardlib
     from repro.optim.schedule import make_schedule
@@ -952,11 +951,11 @@ def make_sharded_round_fn(model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
                               wssl_cfg=wssl_cfg, train_cfg=train_cfg,
                               schedule=schedule, impl=impl, shard_ctx=ctx)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(st_specs, client_spec, rep, rep, rep, rep),
         out_specs=(st_specs, rep),
-        check_rep=False, auto=frozenset(auto))
+        check_vma=False, axis_names=frozenset(dp))
     # donate the incoming WSSLState (default on): the new state aliases
     # the old, so one copy of the sharded per-client stacks + optimizer
     # slots is live at peak.  place_state device_puts a *copy*, so the

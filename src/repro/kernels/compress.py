@@ -73,7 +73,7 @@ def quantize_stochastic_2d(x: jax.Array, u: jax.Array, inv_step: jax.Array,
         ],
         out_specs=pl.BlockSpec((n, block_m), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, mp), jnp.int8),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lv, inv_step, x, u)
@@ -103,7 +103,7 @@ def dequantize_2d(q: jax.Array, step: jax.Array, *, block_m: int = 2048,
         ],
         out_specs=pl.BlockSpec((n, block_m), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, mp), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(step, q)
@@ -137,7 +137,7 @@ def topk_mask_2d(x: jax.Array, thresh: jax.Array, *, block_m: int = 2048,
         ],
         out_specs=pl.BlockSpec((n, block_m), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, mp), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(thresh, xp)

@@ -111,7 +111,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),      # l
             pltpu.VMEM((block_q, hd), jnp.float32),   # acc
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,

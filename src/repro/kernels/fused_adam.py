@@ -100,7 +100,7 @@ def fused_adamw_2d(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
             jax.ShapeDtypeStruct((n, mp), jnp.float32),
             jax.ShapeDtypeStruct((n, mp), jnp.float32),
         ),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(scalars, mask, p, g, m, v)

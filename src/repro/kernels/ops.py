@@ -1,7 +1,8 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute in interpret mode; on real TPU the
-same calls compile to Mosaic.  Every wrapper accepts the model-layer layouts
+On the CPU backend (the tests) kernels execute in the Pallas interpreter;
+on a TPU the same calls compile to Mosaic.  Any other backend is an error,
+never a silent fall-back to the interpreter.  Every wrapper accepts the model-layer layouts
 (e.g. (B,S,H,hd) attention tensors) and handles the transposes/padding.
 """
 
@@ -22,8 +23,13 @@ from repro.kernels import ssd_scan as _ssd
 from repro.kernels import wavg as _wavg
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run on a TPU or, for tests, in the CPU "
+            f"interpreter; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -38,7 +44,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = _fa.flash_attention_bhsd(
         qt, kt, vt, causal=causal, window=window, scale=scale,
         logit_softcap=logit_softcap, block_q=block_q, block_k=block_k,
-        interpret=not _on_tpu())
+        interpret=_interpret())
     return out.transpose(0, 2, 1, 3)
 
 
@@ -52,17 +58,17 @@ def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
     logical view is ever materialized (see kernels/paged_attention.py)."""
     return _pa.paged_decode_attention(
         q, pk, pv, ppos, table, pos, scale=scale,
-        logit_softcap=logit_softcap, interpret=not _on_tpu())
+        logit_softcap=logit_softcap, interpret=_interpret())
 
 
 def ssd_scan(x, dt, a, b_, c_, *, chunk: int = 128, block_h: int = 8):
     return _ssd.ssd_scan(x, dt, a, b_, c_, chunk=chunk, block_h=block_h,
-                         interpret=not _on_tpu())
+                         interpret=_interpret())
 
 
 def rg_lru_scan(log_a, b, *, chunk: int = 128, block_w: int = 512):
     return _lru.rg_lru_scan(log_a, b, chunk=chunk, block_w=block_w,
-                            interpret=not _on_tpu())
+                            interpret=_interpret())
 
 
 def weighted_average(stacked: jax.Array, weights: jax.Array,
@@ -75,7 +81,7 @@ def weighted_average(stacked: jax.Array, weights: jax.Array,
     if flat.shape[1] == 0:
         return jnp.zeros(stacked.shape[1:], stacked.dtype)
     out = _wavg.weighted_average_2d(flat, weights, block_m=block_m,
-                                    interpret=not _on_tpu())
+                                    interpret=_interpret())
     return out.reshape(stacked.shape[1:])
 
 
@@ -104,7 +110,7 @@ def fused_adamw(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
         return p, m.astype(jnp.float32), v.astype(jnp.float32)
     po, mo, vo = _fadam.fused_adamw_2d(pf, gf, mf, vf, rows, scalars,
                                        block_m=block_m,
-                                       interpret=not _on_tpu())
+                                       interpret=_interpret())
     return po.reshape(p.shape), mo.reshape(m.shape), vo.reshape(v.shape)
 
 
@@ -113,18 +119,18 @@ def quantize_stochastic(x: jax.Array, u: jax.Array, inv_step: jax.Array,
     """(N, M) fp -> (N, M) int8 codes in [-levels, levels]."""
     return _compress.quantize_stochastic_2d(x, u, inv_step, levels,
                                             block_m=block_m,
-                                            interpret=not _on_tpu())
+                                            interpret=_interpret())
 
 
 def dequantize(q: jax.Array, step: jax.Array,
                *, block_m: int = 2048) -> jax.Array:
     """(N, M) int8 codes -> (N, M) fp32 reconstruction."""
     return _compress.dequantize_2d(q, step, block_m=block_m,
-                                   interpret=not _on_tpu())
+                                   interpret=_interpret())
 
 
 def topk_mask(x: jax.Array, thresh: jax.Array,
               *, block_m: int = 2048) -> jax.Array:
     """(N, M) fp -> same with |x| < per-row threshold zeroed."""
     return _compress.topk_mask_2d(x, thresh, block_m=block_m,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())

@@ -2,10 +2,13 @@
 
 One-token attention computed *directly against the paged KV pool*: the
 ``(B, nb)`` block table and the per-row positions ride in as
-scalar-prefetch operands, the grid walks ``(batch, kv_head, logical
-block)``, and each step DMAs exactly one pool block ``(bs, hd)`` through
-the table indirection into an online-softmax accumulator (running
-max/sum rescaling, with the ``ppos`` validity mask fused in).  The
+scalar-prefetch operands, the grid walks ``(batch, logical block)``,
+and each step DMAs exactly one pool block ``(bs, Hkv, hd)`` through the
+table indirection into per-KV-head online-softmax accumulators (running
+max/sum rescaling, with the ``ppos`` validity mask fused in).  The block
+spans whole trailing dimensions, which is what Mosaic's tiling accepts
+for any head count and head size; one head's ``(bs, hd)`` slice is read
+from it inside the kernel.  The
 gather path this replaces (``attention.paged_decode_attention``)
 materializes the full ``(B, nb*bs, H, hd)`` logical K and V views in HBM
 every decode step; here no logical view ever exists.
@@ -44,9 +47,9 @@ NEG_INF = -1e30
 def _paged_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, ppos_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float,
                   softcap: Optional[float], block_size: int,
-                  num_blocks: int):
+                  num_blocks: int, num_kv_heads: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -61,31 +64,36 @@ def _paged_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, ppos_ref, o_ref,
     # re-points the DMA at the last live block, so nothing new moved)
     @pl.when(j * block_size <= pos_b)
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)           # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)     # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)       # (G, bs)
-        pp = ppos_ref[0]                              # (bs,)
+        pp = ppos_ref[0]                              # (1, bs)
         valid = (pp >= 0) & (pp <= pos_b)
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        corr = jnp.exp(m_prev - m_new)
-        # zero (not exp-of-huge-negative) the invalid lanes: an all-invalid
-        # prefix keeps l == 0 and finalizes to 0 instead of a garbage mean
-        p = jnp.where(valid[None, :], jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[...] = l_prev * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
+        for h in range(num_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)       # (G, hd)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (bs, hd)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)   # (G, bs)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[h]                         # (G, 1)
+            l_prev = l_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            # zero (not exp-of-huge-negative) the invalid lanes: an
+            # all-invalid prefix keeps l == 0 and finalizes to 0 instead
+            # of a garbage mean
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_ref[h] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == num_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
@@ -97,44 +105,46 @@ def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
     table: (B, nb) int32 logical→physical block map; pos: (B,) int32
     current absolute position per row -> (B, Hq, hd)."""
     b, hq, hd = q.shape
-    _, bs, hkv, _ = pk.shape
+    num_pool, bs, hkv, _ = pk.shape
     nb = table.shape[1]
     g = hq // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     qg = q.reshape(b, hkv, g, hd)
+    # one (1, bs) row of positions per block: a whole-dimension block
+    ppos = ppos.reshape(num_pool, 1, bs)
     pos = jnp.asarray(pos, jnp.int32)
     table = jnp.asarray(table, jnp.int32)
 
-    def kv_map(b_, h, j, pos_ref, table_ref):
+    def kv_map(b_, j, pos_ref, table_ref):
         # clamp the dead tail to the last live block: the repeated block
         # index makes the pipeline skip the DMA instead of streaming
         # max_len - live dead blocks per row
         jl = jnp.minimum(j, pos_ref[b_] // bs)
-        return (table_ref[b_, jl], 0, h, 0)
+        return (table_ref[b_, jl], 0, 0, 0)
 
-    def ppos_map(b_, h, j, pos_ref, table_ref):
+    def ppos_map(b_, j, pos_ref, table_ref):
         jl = jnp.minimum(j, pos_ref[b_] // bs)
-        return (table_ref[b_, jl], 0)
+        return (table_ref[b_, jl], 0, 0)
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, softcap=logit_softcap, block_size=bs,
-        num_blocks=nb)
+        num_blocks=nb, num_kv_heads=hkv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                       # pos, table
-        grid=(b, hkv, nb),
+        grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b_, h, j, p_, t_: (b_, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
-            pl.BlockSpec((1, bs), ppos_map),
+            pl.BlockSpec((1, hkv, g, hd), lambda b_, j, p_, t_: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, bs, hkv, hd), kv_map),
+            pl.BlockSpec((1, bs, hkv, hd), kv_map),
+            pl.BlockSpec((1, 1, bs), ppos_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b_, h, j, p_, t_: (b_, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, g, hd),
+                               lambda b_, j, p_, t_: (b_, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),           # m
-            pltpu.VMEM((g,), jnp.float32),           # l
-            pltpu.VMEM((g, hd), jnp.float32),        # acc
+            pltpu.VMEM((hkv, g, 1), jnp.float32),    # m
+            pltpu.VMEM((hkv, g, 1), jnp.float32),    # l
+            pltpu.VMEM((hkv, g, hd), jnp.float32),   # acc
         ],
     )
 
@@ -142,8 +152,8 @@ def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos, table, qg, pk, pv, ppos)
     return out.reshape(b, hq, hd)

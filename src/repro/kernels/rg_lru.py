@@ -4,7 +4,7 @@ Gates (the W×W matmuls) run outside; the kernel handles the sequential
 recurrence, which on TPU is memory-bound VPU work.  Grid:
 (batch, width_blocks, chunks) — width is blocked so each program touches a
 (Q, bw) tile; the chunk dimension is sequential and the carried hidden
-state (bw,) lives in VMEM scratch.  Within a chunk a fori_loop runs the
+state (1, bw) lives in VMEM scratch.  Within a chunk a fori_loop runs the
 recurrence row by row (the loop is the recurrence — there is no way around
 the sequential dependency; blocking keeps every iteration's operands in
 VMEM/VREGs).
@@ -27,17 +27,17 @@ def _rg_lru_kernel(loga_ref, b_ref, y_ref, h_ref, *, chunk: int):
     def _reset():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    log_a = loga_ref[0].astype(jnp.float32)   # (Q, bw), <= 0
-    b = b_ref[0].astype(jnp.float32)          # (Q, bw)
-    a = jnp.exp(log_a)
-
+    # rows are read from the refs with pl.ds: indexing a loaded value by
+    # the loop counter lowers to dynamic_slice, which Mosaic cannot lower
     def body(t, h):
-        h = a[t] * h + b[t]
-        y_ref[0, t] = h.astype(y_ref.dtype)
+        row = pl.ds(t, 1)
+        a = jnp.exp(loga_ref[0, row, :].astype(jnp.float32))   # (1, bw)
+        h = a * h + b_ref[0, row, :].astype(jnp.float32)
+        y_ref[0, row, :] = h.astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, chunk, body, h_ref[...].astype(jnp.float32))
-    h_ref[...] = h.astype(h_ref.dtype)
+    h = jax.lax.fori_loop(0, chunk, body, h_ref[...])
+    h_ref[...] = h
 
 
 def rg_lru_scan(log_a: jax.Array, b: jax.Array, *, chunk: int = 128,
@@ -59,8 +59,8 @@ def rg_lru_scan(log_a: jax.Array, b: jax.Array, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, chunk, block_w),
                                lambda b__, wi, j: (b__, j, wi)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), b.dtype),
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b)

@@ -23,8 +23,12 @@ from jax.experimental.pallas import tpu as pltpu
 def _wavg_kernel(w_ref, x_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)        # (N, bm)
     w = w_ref[...].astype(jnp.float32)        # (N,)
+    # fp32 contraction on the MXU: the mean of fp32 parameters must not
+    # pass through a bf16 rounding of either operand
     o_ref[...] = jax.lax.dot_general(
-        w[None, :], x, (((1,), (0,)), ((), ())))[0].astype(o_ref.dtype)
+        w[None, :], x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)[0].astype(o_ref.dtype)
 
 
 def weighted_average_2d(stacked: jax.Array, weights: jax.Array, *,
@@ -50,7 +54,7 @@ def weighted_average_2d(stacked: jax.Array, weights: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_m,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((mp,), stacked.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(weights, stacked)

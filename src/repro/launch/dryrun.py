@@ -104,8 +104,6 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0 - t_lower
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     mem = ra.summarize_memory(compiled.memory_analysis())
     hlo = compiled.as_text()
     # XLA's HloCostAnalysis counts while (scan) bodies once; the structural

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.config import WSSLConfig, get_arch, reduced
 from repro.data.synthetic import make_token_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.serve import (DecodeEngine, FaultRoutedServer, ServeParams,
                          get_engine, synthetic_requests)
@@ -91,6 +92,7 @@ def main() -> None:
         ap.error("--cuts only takes effect with --mode split")
     if args.paged_kernel and not args.block_size:
         ap.error("--paged-kernel needs a paged cache (--block-size)")
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

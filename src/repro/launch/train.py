@@ -22,6 +22,7 @@ from repro.checkpoint import save_checkpoint
 from repro.config import TrainConfig, WSSLConfig, get_arch, reduced
 from repro.core.round import init_state, make_round_fn
 from repro.data.synthetic import lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -47,6 +48,7 @@ def main() -> None:
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--log", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -77,6 +79,7 @@ def main() -> None:
                  "labels": jnp.asarray(d["labels"]).reshape(n, b, s)}
         t0 = time.time()
         state, m = round_fn(state, batch, val)
+        jax.block_until_ready(state)   # dt covers the round, not dispatch
         dt = time.time() - t0
         rec = {"round": r, "loss": float(m.loss), "dt_s": dt,
                "selected": int(m.mask.sum()),

@@ -144,25 +144,31 @@ def _scatter_slot_paged_jit(dst: Params, src: Params, slot: jax.Array,
     update (regression-tested via the executable's cost analysis)."""
     nr = blocks.shape[0]
 
-    def write(d, stacked, s):
-        if "pk" in d:
-            if stacked:
-                def resh(a):  # (n_full, 1, max_len, ...) -> (n_full, nr, bs, ...)
-                    nb = a.shape[2] // block_size
-                    a = a.reshape((a.shape[0], nb, block_size) + a.shape[3:])
-                    return a[:, :nr]
-                return {"pk": d["pk"].at[:, blocks].set(resh(s["k"])),
-                        "pv": d["pv"].at[:, blocks].set(resh(s["v"])),
-                        "ppos": d["ppos"].at[:, blocks].set(resh(s["pos"]))
-                                         .at[:, slot].set(-1)}
+    def put(pool, vals, axis):
+        # one in-place dynamic_update_slice per reserved block: a scatter
+        # along the block axis of a layer-stacked pool makes XLA transpose
+        # (copy) the whole pool to bring that axis to the front
+        def body(i, pool):
+            row = jax.lax.dynamic_index_in_dim(vals, i, axis)
+            return jax.lax.dynamic_update_slice_in_dim(pool, row, blocks[i],
+                                                       axis)
+        return jax.lax.fori_loop(0, nr, body, pool)
 
-            def resh(a):      # (1, max_len, ...) -> (nr, bs, ...)
-                nb = a.shape[1] // block_size
-                return a.reshape((nb, block_size) + a.shape[2:])[:nr]
-            return {"pk": d["pk"].at[blocks].set(resh(s["k"])),
-                    "pv": d["pv"].at[blocks].set(resh(s["v"])),
-                    "ppos": d["ppos"].at[blocks].set(resh(s["pos"]))
-                                     .at[slot].set(-1)}
+    def write(d, stacked, s):
+        ax = 1 if stacked else 0
+        if "pk" in d:
+            def resh(a):  # (.., 1, max_len, ...) -> (.., nr, bs, ...)
+                nb = a.shape[ax + 1] // block_size
+                a = a.reshape(a.shape[:ax] + (nb, block_size)
+                              + a.shape[ax + 2:])
+                return jax.lax.slice_in_dim(a, 0, nr, axis=ax)
+            ppos = put(d["ppos"], resh(s["pos"]), ax)
+            wipe = jnp.full(ppos.shape[:ax] + (1,) + ppos.shape[ax + 1:], -1,
+                            ppos.dtype)
+            return {"pk": put(d["pk"], resh(s["k"]), ax),
+                    "pv": put(d["pv"], resh(s["v"]), ax),
+                    "ppos": jax.lax.dynamic_update_slice_in_dim(
+                        ppos, wipe, slot, ax)}
         if stacked:
             return jax.tree.map(lambda dd, ss: dd.at[:, slot].set(ss[:, 0]),
                                 d, s)

@@ -300,3 +300,22 @@ def test_fused_adamw_one_executable_across_hypers():
     for lr in (1e-3, 3e-3, 1e-4):
         _, st = f(params, grads, st, jnp.float32(lr))
     assert f._cache_size() == 1
+
+
+# ---------------------------------------------------------------------------
+# dispatch: interpret on the CPU backend only
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend", ["gpu", "METAL"])
+def test_ops_raise_on_backend_neither_cpu_nor_tpu(backend, monkeypatch):
+    """A kernel never falls back to the interpreter on an accelerator it
+    was not written for: only the CPU backend (the tests) interprets."""
+    from repro.kernels import ops
+    x, w = _rand((4, 256)), jnp.full((4,), 0.25, jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with pytest.raises(RuntimeError, match=backend):
+        ops.weighted_average(x, w)
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    np.testing.assert_allclose(np.asarray(ops.weighted_average(x, w)),
+                               np.asarray(ref.weighted_average_2d(x, w)),
+                               atol=1e-6)

@@ -271,7 +271,6 @@ def test_paged_admission_cost_is_o_reserved():
         dst, src, jnp.asarray(0, jnp.int32),
         jnp.arange(nr, dtype=jnp.int32), bs).compile()
     ca = compiled.cost_analysis()
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
     pool_bytes = sum(int(a.nbytes) for a in jax.tree.leaves(dst))
     assert float(ca["bytes accessed"]) < pool_bytes / 8
 

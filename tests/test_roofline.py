@@ -24,12 +24,6 @@ def _unrolled_fn(w, x):
     return x.sum()
 
 
-def _xla_cost(compiled):
-    """jax <0.4.30 returns [dict] from Compiled.cost_analysis, newer a dict."""
-    c = compiled.cost_analysis()
-    return c[0] if isinstance(c, (list, tuple)) else c
-
-
 @pytest.fixture(scope="module")
 def compiled_pair():
     w = jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)
@@ -47,7 +41,7 @@ def test_parser_applies_trip_counts(compiled_pair):
     assert ts["flops"] == pytest.approx(expected, rel=0.05)
     assert tu["flops"] == pytest.approx(expected, rel=0.05)
     # XLA's own analysis undercounts the scan by ~8x — the bug we fix
-    xla = _xla_cost(cs)
+    xla = cs.cost_analysis()
     assert xla["flops"] < 0.3 * ts["flops"]
 
 
@@ -67,7 +61,7 @@ def test_bytes_same_order_as_xla_on_unrolled(compiled_pair):
     exactly (3.264e9 both — recorded in EXPERIMENTS.md §Dry-run notes)."""
     _, cu = compiled_pair
     tu = analyze_text(cu.as_text())
-    xla = _xla_cost(cu)
+    xla = cu.cost_analysis()
     assert 0.5 * xla["bytes accessed"] < tu["bytes"] < 5 * xla["bytes accessed"]
 
 

@@ -1,11 +1,14 @@
 """Serving subsystem (repro.serve): golden decode parity, compile
 discipline, continuous-batching mechanics, and fault-routed replicas.
 
-``tests/golden/serve_greedy.npz`` was generated by the PRE-refactor
-host-side decode loop (``launch/serve.py`` at the previous commit: fresh
-``jax.jit(lambda ...)`` per call, one dispatch per token) for the three
-architecture families — the scan-fused engine must reproduce its greedy
-tokens bit-for-bit."""
+``tests/golden/serve_greedy.npz`` holds the greedy tokens of the
+pre-refactor host-side decode loop (``_host_loop_generate``: prefill, then
+one jitted ``decode_step`` dispatch per token) for the three architecture
+families — the scan-fused engine must reproduce them bit-for-bit, and so
+must that loop, run alongside.  The params come from ``jax.random``, so
+the golden pins the PRNG implementation too: regenerate it after a JAX
+upgrade that changes the draws with ``PYTHONPATH=src python
+tests/test_serve.py``."""
 
 import math
 import os
@@ -36,6 +39,21 @@ def _setup(arch):
     return cfg, params
 
 
+def _host_loop_generate(params, cfg, prompts, gen):
+    """The pre-refactor greedy decode: one dispatch per token."""
+    s0 = prompts.shape[1]
+    logits, cache = tf.prefill(params, cfg, prompts, max_len=s0 + gen,
+                               impl="dense")
+    decode = jax.jit(lambda p, t, c, pos: tf.decode_step(p, cfg, t, c, pos))
+    tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+    out = [tok]
+    for t in range(gen - 1):
+        logits, cache = decode(params, tok, cache, jnp.asarray(s0 + t))
+        tok = jnp.argmax(logits[:, 0], axis=-1)[:, None].astype(jnp.int32)
+        out.append(tok)
+    return jnp.concatenate(out, axis=1)
+
+
 # ---------------------------------------------------------------------------
 # golden parity + compile discipline
 # ---------------------------------------------------------------------------
@@ -48,8 +66,10 @@ def test_scan_engine_matches_legacy_loop_golden(family):
     g = np.load(GOLDEN)
     cfg, params = _setup(FAMILIES[family])
     eng = DecodeEngine(cfg, impl="dense")
-    toks = eng.generate(params, jnp.asarray(g[f"{family}_prompts"]),
-                        int(g["gen"]))
+    prompts = jnp.asarray(g[f"{family}_prompts"])
+    toks = eng.generate(params, prompts, int(g["gen"]))
+    loop = _host_loop_generate(params, cfg, prompts, int(g["gen"]))
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(loop))
     np.testing.assert_array_equal(np.asarray(toks), g[f"{family}_tokens"])
 
 
@@ -721,3 +741,18 @@ def test_max_ticks_truncation_is_reported():
         ServeParams(replicas=2, slots=8, chunk=8, max_len=64)).run(reqs)
     assert drained.unfinished == 0
     assert sorted(drained.outputs) == [r.rid for r in reqs]
+
+
+def _regenerate_golden():
+    """Rewrite the golden tokens from the host loop (same prompts)."""
+    g = dict(np.load(GOLDEN))
+    for family, arch in FAMILIES.items():
+        cfg, params = _setup(arch)
+        g[f"{family}_tokens"] = np.asarray(_host_loop_generate(
+            params, cfg, jnp.asarray(g[f"{family}_prompts"]), int(g["gen"])))
+    np.savez(GOLDEN, **g)
+
+
+if __name__ == "__main__":
+    jax.config.update("jax_platforms", "cpu")
+    _regenerate_golden()
