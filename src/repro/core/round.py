@@ -355,7 +355,8 @@ def _client_grads_chunked(client_stack: Params,
             return _client_vmap(lambda cp, t: one(cp, t, None))(cstack,
                                                                 toks)
 
-        acts, client_vjp = jax.vjp(client_fn, cs)
+        with jax.named_scope("wssl.client"):
+            acts, client_vjp = jax.vjp(client_fn, cs)
         hop_b = [acts.size // acts.shape[0] * acts.dtype.itemsize]
         wire_shapes = [(acts.size // acts.shape[0] // acts.shape[-1],
                         acts.shape[-1])]
@@ -394,8 +395,9 @@ def _client_grads_chunked(client_stack: Params,
             # the global client mean exactly as the flat/psum paths do
             return jnp.sum(coef * losses) + aux * (chunk / n), losses
 
-        (l_c, pcl_c), (gs_c, g_x) = jax.value_and_grad(
-            server_loss, argnums=(0, 1), has_aux=True)(server_params, x)
+        with jax.named_scope("wssl.server"):
+            (l_c, pcl_c), (gs_c, g_x) = jax.value_and_grad(
+                server_loss, argnums=(0, 1), has_aux=True)(server_params, x)
 
         if compress_acts:
             g_x = compress_mod.compress_activations(
@@ -414,7 +416,8 @@ def _client_grads_chunked(client_stack: Params,
                     comp_cfg, comp_p)
             ge_list.append(g_e)
         ge_list.reverse()
-        (g_cs,) = client_vjp(g_x)
+        with jax.named_scope("wssl.client"):
+            (g_cs,) = client_vjp(g_x)
 
         recorded["hop_bytes"] = hop_b
         recorded["wire_shapes"] = wire_shapes
@@ -503,7 +506,14 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
     replicated (selection and fault draws bit-identical to flat), losses
     and shared-stage gradients cross shards via psum, validation losses
     via all_gather, and aggregation dispatches through the two-level tree
-    (``aggregation.shard_aggregate_clients``)."""
+    (``aggregation.shard_aggregate_clients``).
+
+    The stages run under ``jax.named_scope`` names that reach each
+    compiled op's ``op_name`` (a profiler trace attributes device time by
+    them; they add no op): ``wssl.client`` (client forward and its
+    backward), ``wssl.server`` (server forward, loss and backward),
+    ``wssl.optimizer``, ``wssl.validate`` and ``wssl.aggregate``.
+    Selection, the edge relay and the byte accounting are unscoped."""
     ctx = shard_ctx
     n = wssl_cfg.num_clients
     n_loc = n // ctx.num_shards if ctx is not None else n
@@ -580,7 +590,8 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
             return _client_vmap(lambda cp, t: one(cp, t, None))(cstack,
                                                                 tokens)
 
-        acts, client_vjp = jax.vjp(client_fn, state.client_stack)
+        with jax.named_scope("wssl.client"):
+            acts, client_vjp = jax.vjp(client_fn, state.client_stack)
         acts = shard_activation(acts, "client", None, None, None)
         hop_bytes = [acts.size // acts.shape[0] * acts.dtype.itemsize]
         act_wire_bytes = []
@@ -637,9 +648,10 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
                 total = local + aux
             return total, losses
 
-        (loss, pcl), (g_server, g_x) = jax.value_and_grad(
-            server_loss, argnums=(0, 1), has_aux=True)(
-                state.server_params, x)
+        with jax.named_scope("wssl.server"):
+            (loss, pcl), (g_server, g_x) = jax.value_and_grad(
+                server_loss, argnums=(0, 1), has_aux=True)(
+                    state.server_params, x)
         loss = loss + edge_aux
         # with a ctx the vjp ran per shard on a replicated server stage —
         # each shard's g_server carries only its local clients'
@@ -670,90 +682,95 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
                     comp_cfg, comp_p)
             g_edges.append(_psum(g_e, ctx))
         g_edges.reverse()
-        (g_client,) = client_vjp(g_x)
+        with jax.named_scope("wssl.client"):
+            (g_client,) = client_vjp(g_x)
 
-    if train_cfg.grad_clip:
-        g_client, _ = clip_by_global_norm(
-            g_client, train_cfg.grad_clip,
-            axis_name=ctx.axis if ctx is not None else None)
-        g_server, _ = clip_by_global_norm(g_server, train_cfg.grad_clip)
-        g_edges = [clip_by_global_norm(g, train_cfg.grad_clip)[0]
-                   for g in g_edges]
+    # ---- optimizer: clip, step (masked for unselected clients), faults ---
+    with jax.named_scope("wssl.optimizer"):
+        if train_cfg.grad_clip:
+            g_client, _ = clip_by_global_norm(
+                g_client, train_cfg.grad_clip,
+                axis_name=ctx.axis if ctx is not None else None)
+            g_server, _ = clip_by_global_norm(g_server, train_cfg.grad_clip)
+            g_edges = [clip_by_global_norm(g, train_cfg.grad_clip)[0]
+                       for g in g_edges]
 
-    if plan is not None:
-        # adversarial corruption models the *sent* client update, so it
-        # applies after the shared global-norm clip — otherwise one
-        # adversary's noise inflates the joint norm and attenuates every
-        # clean client's gradient through the clip factor
-        g_client = sim_faults.corrupt_client_grads(
-            plan_loc, g_client,
-            jax.random.fold_in(rng_sel, 0xBAD) if ctx is None
-            else jax.random.fold_in(jax.random.fold_in(rng_sel, 0xBAD),
-                                    ctx.index))
+        if plan is not None:
+            # adversarial corruption models the *sent* client update, so
+            # it applies after the shared global-norm clip — otherwise one
+            # adversary's noise inflates the joint norm and attenuates
+            # every clean client's gradient through the clip factor
+            g_client = sim_faults.corrupt_client_grads(
+                plan_loc, g_client,
+                jax.random.fold_in(rng_sel, 0xBAD) if ctx is None
+                else jax.random.fold_in(jax.random.fold_in(rng_sel, 0xBAD),
+                                        ctx.index))
 
-    # ---- optimizer (masked for unselected clients) ---------------------
-    _, opt_update = make_optimizer(train_cfg.optimizer)
-    okw = _opt_kwargs(train_cfg)
-    lr = schedule(state.round_index)
-    new_cstack, new_opt_c = opt_update(
-        state.client_stack, g_client, state.opt_client, lr=lr,
-        weight_decay=train_cfg.weight_decay, mask=mask_loc, **okw)
-    new_server, new_opt_s = opt_update(
-        state.server_params, g_server, state.opt_server, lr=lr,
-        weight_decay=train_cfg.weight_decay, **okw)
-    new_edges, new_opt_e = [], []
-    for ep, ge, oe in zip(state.edge_stages, g_edges, state.opt_edge):
-        ne, no = opt_update(ep, ge, oe, lr=lr,
-                            weight_decay=train_cfg.weight_decay, **okw)
-        new_edges.append(ne)
-        new_opt_e.append(no)
-    if plan is not None:
-        # straggler / slow-hop partial progress and Byzantine amplification
-        # on the post-optimizer update (a constant gradient scale would be
-        # inert under Adam)
-        new_cstack = sim_faults.scale_client_updates(plan_loc, new_cstack,
-                                                     state.client_stack)
-        # adaptive adversaries craft their sent stage from the round's
-        # honest updates (mean − z·std) — inside the honest spread, so
-        # importance down-weighting cannot catch them
-        new_cstack = sim_faults.adaptive_scale_updates(
-            plan_loc, new_cstack, state.client_stack, mask_loc,
-            axis_name=ctx.axis if ctx is not None else None)
-        # an all-dropped round must leave the shared stages untouched too:
-        # with no participants the CE term is zero but the aux term and
-        # weight decay would still step (and decay) them every empty round
-        alive = mask.sum() > 0
-        keep_old = lambda new, old: jax.tree.map(
-            lambda a, b: jnp.where(alive, a, b), new, old)
-        new_server = keep_old(new_server, state.server_params)
-        new_opt_s = keep_old(new_opt_s, state.opt_server)
-        new_edges = [keep_old(ne, oe)
-                     for ne, oe in zip(new_edges, state.edge_stages)]
-        new_opt_e = [keep_old(no, oo)
-                     for no, oo in zip(new_opt_e, state.opt_edge)]
-    new_edges = tuple(new_edges)
-    new_opt_e = tuple(new_opt_e)
+        _, opt_update = make_optimizer(train_cfg.optimizer)
+        okw = _opt_kwargs(train_cfg)
+        lr = schedule(state.round_index)
+        new_cstack, new_opt_c = opt_update(
+            state.client_stack, g_client, state.opt_client, lr=lr,
+            weight_decay=train_cfg.weight_decay, mask=mask_loc, **okw)
+        new_server, new_opt_s = opt_update(
+            state.server_params, g_server, state.opt_server, lr=lr,
+            weight_decay=train_cfg.weight_decay, **okw)
+        new_edges, new_opt_e = [], []
+        for ep, ge, oe in zip(state.edge_stages, g_edges, state.opt_edge):
+            ne, no = opt_update(ep, ge, oe, lr=lr,
+                                weight_decay=train_cfg.weight_decay, **okw)
+            new_edges.append(ne)
+            new_opt_e.append(no)
+        if plan is not None:
+            # straggler / slow-hop partial progress and Byzantine
+            # amplification on the post-optimizer update (a constant
+            # gradient scale would be inert under Adam)
+            new_cstack = sim_faults.scale_client_updates(
+                plan_loc, new_cstack, state.client_stack)
+            # adaptive adversaries craft their sent stage from the round's
+            # honest updates (mean − z·std) — inside the honest spread, so
+            # importance down-weighting cannot catch them
+            new_cstack = sim_faults.adaptive_scale_updates(
+                plan_loc, new_cstack, state.client_stack, mask_loc,
+                axis_name=ctx.axis if ctx is not None else None)
+            # an all-dropped round must leave the shared stages untouched
+            # too: with no participants the CE term is zero but the aux term
+            # and weight decay would still step (and decay) them every empty
+            # round
+            alive = mask.sum() > 0
+            keep_old = lambda new, old: jax.tree.map(
+                lambda a, b: jnp.where(alive, a, b), new, old)
+            new_server = keep_old(new_server, state.server_params)
+            new_opt_s = keep_old(new_opt_s, state.opt_server)
+            new_edges = [keep_old(ne, oe)
+                         for ne, oe in zip(new_edges, state.edge_stages)]
+            new_opt_e = [keep_old(no, oo)
+                         for no, oo in zip(new_opt_e, state.opt_edge)]
+        new_edges = tuple(new_edges)
+        new_opt_e = tuple(new_opt_e)
 
     # ---- validation on the server-held ζ → importance ------------------
     if val_batch is not None:
-        vt, vl = val_batch["tokens"], val_batch["labels"]
+        with jax.named_scope("wssl.validate"):
+            vt, vl = val_batch["tokens"], val_batch["labels"]
 
-        def val_one(cp):
-            a = tf.client_forward(cp, model_cfg, vt, impl=impl, remat=remat)
-            for j in range(num_edges):
-                a = tf.stage_forward(new_edges[j], model_cfg, a, j + 1,
-                                     impl=impl, remat=remat)
-            loss, _ = tf.server_loss(new_server, model_cfg, a, vl,
-                                     impl=impl, remat=remat)
-            return loss
+            def val_one(cp):
+                a = tf.client_forward(cp, model_cfg, vt, impl=impl,
+                                      remat=remat)
+                for j in range(num_edges):
+                    a = tf.stage_forward(new_edges[j], model_cfg, a, j + 1,
+                                         impl=impl, remat=remat)
+                loss, _ = tf.server_loss(new_server, model_cfg, a, vl,
+                                         impl=impl, remat=remat)
+                return loss
 
-        if chunk is not None:
-            vl_loc = _chunked_client_map(val_one, new_cstack, chunk)
-        else:
-            vl_loc = _client_vmap(val_one)(new_cstack)
-        val_losses = _gather(vl_loc, ctx)
-        importance = wssl.compute_importance(val_losses, wssl_cfg,
-                                             prev=state.importance)
+            if chunk is not None:
+                vl_loc = _chunked_client_map(val_one, new_cstack, chunk)
+            else:
+                vl_loc = _client_vmap(val_one)(new_cstack)
+            val_losses = _gather(vl_loc, ctx)
+            importance = wssl.compute_importance(val_losses, wssl_cfg,
+                                                 prev=state.importance)
     else:
         val_losses = jnp.zeros((n,), jnp.float32)
         importance = state.importance
@@ -763,39 +780,42 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
     # reconstructs old + decompress(compress(Δ + e)) before aggregation,
     # so every registry rule runs on the wire-reconstructed updates.  With
     # scheme="none" this whole block is absent from the trace.
-    ef_residual = state.ef_residual
-    if comp_cfg.enabled:
-        delta = jax.tree.map(lambda a, b: a.astype(jnp.float32)
-                             - b.astype(jnp.float32),
-                             new_cstack, state.client_stack)
-        rng_comp = jax.random.fold_in(rng_sel, 0xC09)
-        if ctx is not None:
-            # decorrelate the per-coordinate stochastic draws across
-            # shards (the flat round draws one (N, m) tensor per leaf;
-            # per-shard draws necessarily differ — documented tolerance)
-            rng_comp = jax.random.fold_in(rng_comp, ctx.index)
-        sent, ef_residual = compress_mod.apply_compression(
-            delta, ef_residual, mask_loc, rng_comp, comp_cfg, comp_p)
-        agg_stack = jax.tree.map(
-            lambda old, s: (old.astype(jnp.float32) + s).astype(old.dtype),
-            state.client_stack, sent)
-    else:
-        agg_stack = new_cstack
+    with jax.named_scope("wssl.aggregate"):
+        ef_residual = state.ef_residual
+        if comp_cfg.enabled:
+            delta = jax.tree.map(lambda a, b: a.astype(jnp.float32)
+                                 - b.astype(jnp.float32),
+                                 new_cstack, state.client_stack)
+            rng_comp = jax.random.fold_in(rng_sel, 0xC09)
+            if ctx is not None:
+                # decorrelate the per-coordinate stochastic draws across
+                # shards (the flat round draws one (N, m) tensor per leaf;
+                # per-shard draws necessarily differ — documented tolerance)
+                rng_comp = jax.random.fold_in(rng_comp, ctx.index)
+            sent, ef_residual = compress_mod.apply_compression(
+                delta, ef_residual, mask_loc, rng_comp, comp_cfg, comp_p)
+            agg_stack = jax.tree.map(
+                lambda old, s: (old.astype(jnp.float32)
+                                + s).astype(old.dtype),
+                state.client_stack, sent)
+        else:
+            agg_stack = new_cstack
 
-    # ---- Algorithm 2 step 5: registry-dispatched aggregation + sync -----
-    # (dropout can empty the selection; `safe` falls back to a no-op sync)
-    if ctx is None:
-        global_client = aggregation.aggregate_clients(
-            agg_stack, importance, mask, wssl_cfg, safe=plan is not None,
-            params=agg_p)
-    else:
-        # two-level tree: per-shard partial aggregate, psum combine (or
-        # the documented all_gather fallback for non-decomposable rules)
-        global_client = aggregation.shard_aggregate_clients(
-            agg_stack, importance, mask, wssl_cfg, axis_name=ctx.axis,
-            shard_index=ctx.index, num_shards=ctx.num_shards,
-            safe=plan is not None, params=agg_p)
-    new_cstack = wssl.broadcast_global(new_cstack, global_client)
+        # ---- Algorithm 2 step 5: registry-dispatched aggregation + sync
+        # (dropout can empty the selection; `safe` falls back to a no-op
+        # sync)
+        if ctx is None:
+            global_client = aggregation.aggregate_clients(
+                agg_stack, importance, mask, wssl_cfg, safe=plan is not None,
+                params=agg_p)
+        else:
+            # two-level tree: per-shard partial aggregate, psum combine (or
+            # the documented all_gather fallback for non-decomposable rules)
+            global_client = aggregation.shard_aggregate_clients(
+                agg_stack, importance, mask, wssl_cfg, axis_name=ctx.axis,
+                shard_index=ctx.index, num_shards=ctx.num_shards,
+                safe=plan is not None, params=agg_p)
+        new_cstack = wssl.broadcast_global(new_cstack, global_client)
 
     # ---- communication accounting --------------------------------------
     sel = mask.sum()

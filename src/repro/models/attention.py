@@ -404,19 +404,22 @@ def multihead_attention(cfg: ModelConfig, p: Params, x: jax.Array,
         pos1d = positions[..., 0]
     else:
         pos1d = positions
-    if impl == "banded" and window is not None:
-        out = _attn_banded(cfg, q, k, v, pos1d, pos1d, window)
-    elif impl == "dense":
-        out = _attn_dense(cfg, q, k, v, pos1d, pos1d, window)
-    elif impl in ("chunked", "banded", "flash"):
-        # flash custom-vjp core: memory-bounded forward AND backward
-        out = _attn_flash(cfg, q, k, v, pos1d, pos1d, window)
-    elif impl == "triangular":
-        out = _attn_triangular(cfg, q, k, v, pos1d, pos1d, window)
-    elif impl == "pallas":
-        out = _attn_pallas(cfg, q, k, v, pos1d, pos1d, window)
-    else:
-        raise ValueError(f"unknown attn impl {impl!r}")
+    # scores, mask, softmax and AV under one name a profiler trace can
+    # attribute device time by; the projections stay outside it
+    with jax.named_scope("attn_core"):
+        if impl == "banded" and window is not None:
+            out = _attn_banded(cfg, q, k, v, pos1d, pos1d, window)
+        elif impl == "dense":
+            out = _attn_dense(cfg, q, k, v, pos1d, pos1d, window)
+        elif impl in ("chunked", "banded", "flash"):
+            # flash custom-vjp core: memory-bounded forward AND backward
+            out = _attn_flash(cfg, q, k, v, pos1d, pos1d, window)
+        elif impl == "triangular":
+            out = _attn_triangular(cfg, q, k, v, pos1d, pos1d, window)
+        elif impl == "pallas":
+            out = _attn_pallas(cfg, q, k, v, pos1d, pos1d, window)
+        else:
+            raise ValueError(f"unknown attn impl {impl!r}")
     out = shard_activation(out, "batch", "attn_seq", "act_heads", None)
     dtype = x.dtype
     return jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(dtype))
