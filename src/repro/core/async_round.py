@@ -52,10 +52,9 @@ from repro import compress as compress_mod
 from repro.core import aggregation, wssl
 from repro.core.protocol import hierarchical_sync_bytes, sync_round_bytes
 from repro.core.round import (RoundMetrics, ShardCtx, WSSLState,
-                              _chunked_client_map, _client_grads_chunked,
-                              _client_stage_bytes, _client_vmap, _gather,
-                              _loc, _local_plan, _opt_kwargs,
-                              _per_client_losses, _psum)
+                              _chunked_client_map, _client_stage_bytes,
+                              _client_vmap, _gather, _loc, _local_plan,
+                              _opt_kwargs, _split_grads)
 from repro.models import transformer as tf
 from repro.optim import clip_by_global_norm, make_optimizer
 from repro.sim import faults as sim_faults
@@ -190,8 +189,11 @@ def async_wssl_round(state: WSSLState, astate: AsyncState,
     if wssl_cfg.select_staleness_beta:
         penalty = (sim_faults.client_latencies(plan, n) - 1.0
                    + astate.pending.astype(jnp.float32))
+    idx = wssl.weighted_sample(rng_sel, state.importance,
+                               wssl_cfg.num_selected(), penalty=penalty,
+                               beta=wssl_cfg.select_staleness_beta)
     mask = wssl.participation_mask(rng_sel, state.importance, wssl_cfg,
-                                   state.round_index, penalty=penalty)
+                                   state.round_index, idx=idx)
 
     # dropout ⇒ zero-mask
     if plan is not None:
@@ -239,100 +241,14 @@ def async_wssl_round(state: WSSLState, astate: AsyncState,
                                            model_cfg.vocab_size)
     embeds = batch.get("embeds")
 
-    # ---- split fwd / chained N-phase backward (as in wssl_round) --------
-    span = train_cfg.remat_span
-    chunk = train_cfg.client_chunk
-    if chunk is not None:
-        # client-chunked scan (shared with the sync round): the async
-        # round's CE weight is agg_w·part instead of agg_w·mask
-        (loss, pcl, g_client, g_server, g_edges, hop_bytes,
-         act_wire_bytes) = _client_grads_chunked(
-            state.client_stack, state.edge_stages, state.server_params,
-            tokens, labels, embeds, agg_w_loc * part_loc,
-            model_cfg=model_cfg, train_cfg=train_cfg, impl=impl,
-            chunk=chunk, n=n, n_loc=n_loc, ctx=ctx, comp_cfg=comp_cfg,
-            comp_p=comp_p, compress_acts=compress_acts, rng_sel=rng_sel)
-    else:
-        def client_fn(cstack):
-            def one(cp, toks, emb):
-                return tf.client_forward(cp, model_cfg, toks, embeds=emb,
-                                         impl=impl, remat=remat,
-                                         remat_span=span)
-            if embeds is not None:
-                return _client_vmap(one)(cstack, tokens, embeds)
-            return _client_vmap(lambda cp, t: one(cp, t, None))(cstack,
-                                                                tokens)
-
-        acts, client_vjp = jax.vjp(client_fn, state.client_stack)
-        acts = shard_activation(acts, "client", None, None, None)
-        hop_bytes = [acts.size // acts.shape[0] * acts.dtype.itemsize]
-        act_wire_bytes = []
-        if compress_acts:
-            acts = compress_mod.compress_activations(
-                acts, jax.random.fold_in(rng_sel, 0xAC0), comp_cfg, comp_p)
-            act_wire_bytes.append(compress_mod.activation_wire_bytes(
-                acts.size // acts.shape[0] // acts.shape[-1],
-                acts.shape[-1], comp_cfg, comp_p))
-
-        x, edge_vjps = acts, []
-        edge_aux = jnp.zeros((), jnp.float32)
-        for j in range(num_edges):
-            def edge_fn(p, a, j=j):
-                return _client_vmap(
-                    lambda pi, ai: tf.stage_forward(pi, model_cfg, ai,
-                                                    j + 1, impl=impl,
-                                                    remat=remat,
-                                                    remat_span=span,
-                                                    with_aux=True),
-                    in_axes=(None, 0))(p, a)
-            (x, aux_j), vjp = jax.vjp(edge_fn, state.edge_stages[j], x)
-            x = shard_activation(x, "client", None, None, None)
-            edge_aux = edge_aux + (
-                _psum(aux_j.mean(), ctx) / ctx.num_shards
-                if ctx is not None else aux_j.mean())
-            edge_vjps.append(vjp)
-            hop_bytes.append(x.size // x.shape[0] * x.dtype.itemsize)
-            if compress_acts:
-                x = compress_mod.compress_activations(
-                    x, jax.random.fold_in(rng_sel, 0xAC1 + j), comp_cfg,
-                    comp_p)
-                act_wire_bytes.append(compress_mod.activation_wire_bytes(
-                    x.size // x.shape[0] // x.shape[-1], x.shape[-1],
-                    comp_cfg, comp_p))
-
-        def server_loss(sp, a):
-            losses, aux = _per_client_losses(model_cfg, sp, a, labels,
-                                             impl, remat, span)
-            local = jnp.sum(agg_w_loc * part_loc * losses)
-            if ctx is not None:
-                total = (jax.lax.psum(local, ctx.axis)
-                         + jax.lax.psum(aux, ctx.axis) / ctx.num_shards)
-            else:
-                total = local + aux
-            return total, losses
-
-        (loss, pcl), (g_server, g_x) = jax.value_and_grad(
-            server_loss, argnums=(0, 1), has_aux=True)(
-                state.server_params, x)
-        loss = loss + edge_aux
-        g_server = _psum(g_server, ctx)
-
-        if compress_acts:
-            g_x = compress_mod.compress_activations(
-                g_x, jax.random.fold_in(rng_sel, 0xDC0 + num_edges),
-                comp_cfg, comp_p)
-        aux_ct = jnp.full((n_loc,), 1.0 / n, jnp.float32)
-        g_edges = []
-        for back_j, vjp in enumerate(reversed(edge_vjps)):
-            g_e, g_x = vjp((g_x, aux_ct))
-            if compress_acts:
-                g_x = compress_mod.compress_activations(
-                    g_x, jax.random.fold_in(rng_sel,
-                                            0xDC0 + num_edges - 1 - back_j),
-                    comp_cfg, comp_p)
-            g_edges.append(_psum(g_e, ctx))
-        g_edges.reverse()
-        (g_client,) = client_vjp(g_x)
+    # ---- split fwd / chained N-phase backward (shared with wssl_round):
+    # the async round's CE weight is agg_w·part instead of agg_w·mask ----
+    (loss, pcl, g_client, g_server, g_edges, hop_bytes, act_wire_bytes,
+     work_rows) = _split_grads(
+        state, tokens, labels, embeds, agg_w_loc * part_loc, idx,
+        model_cfg=model_cfg, wssl_cfg=wssl_cfg, train_cfg=train_cfg,
+        impl=impl, n=n, n_loc=n_loc, ctx=ctx, comp_cfg=comp_cfg,
+        comp_p=comp_p, compress_acts=compress_acts, rng_sel=rng_sel)
 
     if train_cfg.grad_clip:
         g_client, _ = clip_by_global_norm(
@@ -408,8 +324,9 @@ def async_wssl_round(state: WSSLState, astate: AsyncState,
                                      impl=impl, remat=remat)
             return loss
 
-        if chunk is not None:
-            vl_loc = _chunked_client_map(val_one, new_cstack, chunk)
+        if train_cfg.client_chunk is not None:
+            vl_loc = _chunked_client_map(val_one, new_cstack,
+                                         train_cfg.client_chunk)
         else:
             vl_loc = _client_vmap(val_one)(new_cstack)
         val_losses = _gather(vl_loc, ctx)
@@ -533,6 +450,7 @@ def async_wssl_round(state: WSSLState, astate: AsyncState,
         bytes_update_comp=update_comp,
         bytes_cross_shard=cross, bytes_intra_shard=intra,
         bytes_act_raw=act_raw, bytes_act_comp=act_comp,
+        work_rows=work_rows,
     )
     amet = AsyncRoundMetrics(
         base=metrics,

@@ -16,9 +16,14 @@ client's activation flows through.  A length-1 cut tuple
 (``WSSLConfig.resolve_cuts``) has no edge stages and reproduces the classic
 two-stage protocol bit-for-bit.
 
-Unselected clients are *masked*, not removed — shapes stay static so one
-compiled executable serves every round, and on a TPU mesh each client group
-simply multiplies by 0/1 (DESIGN.md §2).
+Shapes stay static, so one compiled executable serves every round: k =
+``WSSLConfig.num_selected()`` is static, and after round 0 (which selects
+everyone) the split forward/backward runs on the k selected clients'
+rows alone, gathered from the stack (``_split_grads``).  Where k rows
+would change the objective or need routing — the sharded and
+client-chunked rounds, activation compression, MoE models — unselected
+clients are *masked* instead: they run with loss weight 0 and their exact
+zero gradients are ignored by the masked optimizer (docs/scaling.md).
 """
 
 from __future__ import annotations
@@ -35,7 +40,7 @@ from repro.core import aggregation, wssl
 from repro.core.protocol import hierarchical_sync_bytes, sync_round_bytes
 from repro.models import transformer as tf
 from repro.sim import faults as sim_faults
-from repro.optim import adamw_update, clip_by_global_norm, make_optimizer
+from repro.optim import clip_by_global_norm, make_optimizer
 from repro.sharding import bound_axes, current_mesh, shard_activation
 
 Params = Any
@@ -127,6 +132,9 @@ class RoundMetrics(NamedTuple):
     # wire bytes of the per-hop crossings, both directions (0.0 when off)
     bytes_act_raw: jax.Array = 0.0
     bytes_act_comp: jax.Array = 0.0
+    # client rows whose split forward and backward ran this round: k·b
+    # where only the selected clients run, N·b where all are masked
+    work_rows: jax.Array = 0.0
 
 
 def init_state(rng, model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
@@ -462,6 +470,253 @@ def _client_grads_chunked(client_stack: Params,
             recorded["hop_bytes"], act_wire_bytes)
 
 
+def _client_grads_flat(client_stack: Params,
+                       edge_stages: Tuple[Params, ...],
+                       server_params: Params, tokens, labels, embeds,
+                       coef: jax.Array, *, model_cfg: ModelConfig,
+                       train_cfg: TrainConfig, impl: str, n: int,
+                       ctx: Optional[ShardCtx], comp_cfg, comp_p,
+                       compress_acts: bool, rng_sel):
+    """The per-client split fwd / chained N-phase backward over the
+    stacked rows it is given, vmapped in one piece: every local client,
+    or the k gathered by :func:`_split_grads`.  A row with CE weight 0
+    comes back with exact zero gradients.
+
+    ``coef`` is the per-row CE weight (agg_w · mask); ``n`` the global
+    client count.  Returns ``(loss, pcl, g_client, g_server, g_edges,
+    hop_bytes, act_wire_bytes)``."""
+    remat = train_cfg.remat
+    span = train_cfg.remat_span
+    num_edges = len(edge_stages)
+
+    def client_fn(cstack):
+        def one(cp, toks, emb):
+            return tf.client_forward(cp, model_cfg, toks, embeds=emb,
+                                     impl=impl, remat=remat,
+                                     remat_span=span)
+        if embeds is not None:
+            return _client_vmap(one)(cstack, tokens, embeds)
+        return _client_vmap(lambda cp, t: one(cp, t, None))(cstack, tokens)
+
+    with jax.named_scope("wssl.client"):
+        acts, client_vjp = jax.vjp(client_fn, client_stack)
+    acts = shard_activation(acts, "client", None, None, None)
+    hop_bytes = [acts.size // acts.shape[0] * acts.dtype.itemsize]
+    act_wire_bytes = []
+    if compress_acts:
+        acts = compress_mod.compress_activations(
+            acts, jax.random.fold_in(rng_sel, 0xAC0), comp_cfg, comp_p)
+        act_wire_bytes.append(compress_mod.activation_wire_bytes(
+            acts.size // acts.shape[0] // acts.shape[-1],
+            acts.shape[-1], comp_cfg, comp_p))
+
+    # forward relay through the shared edge stages (per-client
+    # activations, shared params: vmap over the client axis with
+    # in_axes=None params).  Each edge stage also reports its MoE aux
+    # loss so the objective stays invariant to where the cuts sit.
+    x, edge_vjps = acts, []
+    edge_aux = jnp.zeros((), jnp.float32)
+    for j in range(num_edges):
+        def edge_fn(p, a, j=j):
+            return _client_vmap(
+                lambda pi, ai: tf.stage_forward(pi, model_cfg, ai, j + 1,
+                                                impl=impl, remat=remat,
+                                                remat_span=span,
+                                                with_aux=True),
+                in_axes=(None, 0))(p, a)
+        (x, aux_j), vjp = jax.vjp(edge_fn, edge_stages[j], x)
+        x = shard_activation(x, "client", None, None, None)
+        # aux_j.mean() is the mean over the clients in view; with a ctx
+        # that view is local, so psum/S completes the global mean exactly
+        # (equal shard sizes)
+        edge_aux = edge_aux + (
+            _psum(aux_j.mean(), ctx) / ctx.num_shards
+            if ctx is not None else aux_j.mean())
+        edge_vjps.append(vjp)
+        hop_bytes.append(x.size // x.shape[0] * x.dtype.itemsize)
+        if compress_acts:
+            x = compress_mod.compress_activations(
+                x, jax.random.fold_in(rng_sel, 0xAC1 + j), comp_cfg, comp_p)
+            act_wire_bytes.append(compress_mod.activation_wire_bytes(
+                x.size // x.shape[0] // x.shape[-1], x.shape[-1],
+                comp_cfg, comp_p))
+
+    def server_loss(sp, a):
+        losses, aux = _per_client_losses(model_cfg, sp, a, labels, impl,
+                                         remat, span)
+        local = jnp.sum(coef * losses)
+        if ctx is not None:
+            # the CE term sums over all clients; the MoE aux is a mean
+            # over clients, so psum of per-shard means / S completes it
+            total = (jax.lax.psum(local, ctx.axis)
+                     + jax.lax.psum(aux, ctx.axis) / ctx.num_shards)
+        else:
+            total = local + aux
+        return total, losses
+
+    with jax.named_scope("wssl.server"):
+        (loss, pcl), (g_server, g_x) = jax.value_and_grad(
+            server_loss, argnums=(0, 1), has_aux=True)(server_params, x)
+    loss = loss + edge_aux
+    # with a ctx the vjp ran per shard on a replicated server stage — each
+    # shard's g_server carries only its local clients' contribution; the
+    # psum completes the global gradient (and keeps it replicated)
+    g_server = _psum(g_server, ctx)
+
+    # backward relay: inject each hop's cotangent upstream (the mean-aux
+    # term contributes 1/N per client alongside the activation cotangent)
+    if compress_acts:
+        # down-hop wire compression: the returned server→edge gradient is
+        # itself a (N, b, s, d) activation-shaped tensor; chaining the
+        # lossy reconstruction into the manual vjp relay makes the
+        # backward a straight-through estimate of the compressed forward
+        g_x = compress_mod.compress_activations(
+            g_x, jax.random.fold_in(rng_sel, 0xDC0 + num_edges),
+            comp_cfg, comp_p)
+    aux_ct = jnp.full((tokens.shape[0],), 1.0 / n, jnp.float32)
+    g_edges = []
+    for back_j, vjp in enumerate(reversed(edge_vjps)):
+        g_e, g_x = vjp((g_x, aux_ct))
+        if compress_acts:
+            g_x = compress_mod.compress_activations(
+                g_x, jax.random.fold_in(rng_sel,
+                                        0xDC0 + num_edges - 1 - back_j),
+                comp_cfg, comp_p)
+        g_edges.append(_psum(g_e, ctx))
+    g_edges.reverse()
+    with jax.named_scope("wssl.client"):
+        (g_client,) = client_vjp(g_x)
+    return (loss, pcl, g_client, g_server, g_edges, hop_bytes,
+            act_wire_bytes)
+
+
+def _gathers_selected(model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
+                      train_cfg: TrainConfig,
+                      ctx: Optional[ShardCtx]) -> bool:
+    """Static: do rounds after the first run the split fwd/bwd on the k
+    selected clients alone?  Only where k rows give the masked round's
+    objective exactly and need no routing, so the masked path stays for
+    a sharded round (the selected rows live on other shards), under a
+    mesh that shards the client axis (the same, in GSPMD form), for the
+    client-chunked scan, for activation compression (its draws are
+    (N, ...)-shaped per hop), for MoE models (the aux term is a mean over
+    all N clients) and where every client is selected anyway."""
+    comp = wssl_cfg.compression
+    return (ctx is None and train_cfg.client_chunk is None
+            and not (comp.enabled and comp.activations)
+            and model_cfg.num_experts == 0
+            and _client_spmd_axes() is None
+            and wssl_cfg.num_selected() < wssl_cfg.num_clients)
+
+
+def _split_grads(state: WSSLState, tokens, labels, embeds,
+                 coef_loc: jax.Array, idx: jax.Array, *,
+                 model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
+                 train_cfg: TrainConfig, impl: str, n: int, n_loc: int,
+                 ctx: Optional[ShardCtx], comp_cfg, comp_p,
+                 compress_acts: bool, rng_sel):
+    """Algorithm 2 steps 2-4 for both rounds (sync and async).
+
+    Where :func:`_gathers_selected`, one jitted k-row block (gather k
+    clients' stages, rows and CE weights; run the flat block on them)
+    serves every round.  Later rounds run it once, on the k selected
+    ``idx``.  Round 0 selects everyone and runs it on ceil(N/k) groups of
+    k clients (the last padded with clients already covered, at weight
+    0): the first group where later rounds run the selection, the others
+    under a ``lax.cond`` on ``round_index == 0``.  The block's shapes are
+    the same at every call, so it is traced once (a separate N-row block
+    for round 0 would trace the fwd/bwd a second time, which dominates a
+    warm start); the compiled program still holds it at both call sites.
+    Round 0's shared-stage gradients are summed over the groups in fp32,
+    a reassociation of the masked block's sum.  Elsewhere the
+    client-chunked scan or the flat block runs over every local client.
+
+    ``coef_loc`` is the (n_loc,) CE weight.  The gathered path relies on
+    it being zero off ``idx`` after round 0 (the caller's mask, or the
+    async round's ``part``, is ``selection_mask(idx)`` times keep/admit
+    factors): a weighted client outside ``idx`` would be dropped without
+    notice.  Returns ``(loss, pcl, g_client, g_server, g_edges,
+    hop_bytes, act_wire_bytes, work_rows)`` shaped as the masked block
+    gives them (zeros off the rows that ran); ``work_rows`` counts those
+    rows."""
+    args = (state.edge_stages, state.server_params)
+    kw = dict(model_cfg=model_cfg, train_cfg=train_cfg, impl=impl, n=n,
+              ctx=ctx, comp_cfg=comp_cfg, comp_p=comp_p,
+              compress_acts=compress_acts, rng_sel=rng_sel)
+    b = tokens.shape[1]
+    every = jnp.asarray(n * b, jnp.float32)
+    if train_cfg.client_chunk is not None:
+        # client-chunked scan: O(chunk) activation peak, flat semantics
+        # (documented fp band — see _client_grads_chunked)
+        return _client_grads_chunked(
+            state.client_stack, *args, tokens, labels, embeds, coef_loc,
+            chunk=train_cfg.client_chunk, n_loc=n_loc, **kw) + (every,)
+    if not _gathers_selected(model_cfg, wssl_cfg, train_cfg, ctx):
+        return _client_grads_flat(state.client_stack, *args, tokens, labels,
+                                  embeds, coef_loc, **kw) + (every,)
+
+    k = idx.shape[0]
+    passes = -(-n // k)
+    first = state.round_index == 0
+    seats = jnp.arange(passes * k)
+    groups = jnp.where(first, (seats % n).reshape(passes, k), jnp.sort(idx))
+    live = jnp.where(first, (seats < n).reshape(passes, k), True)
+    # the per-hop byte counts are static and per client; recorded as the
+    # block traces
+    static = {}
+
+    @jax.jit
+    def block(rows, ok):
+        with jax.named_scope("wssl.client"):
+            take = lambda a: None if a is None else jnp.take(a, rows, axis=0)
+            rows_in = (jax.tree.map(take, state.client_stack), *args,
+                       take(tokens), take(labels), take(embeds),
+                       take(coef_loc) * ok)
+        out = _client_grads_flat(*rows_in, **kw)
+        static["bytes"] = out[5:]
+        return out[:5]
+
+    rows, ok = groups[0], live[0]
+    l, p, gc, gs, ge = block(rows, ok)
+
+    def placed():
+        # the k rows' per-client outputs into the (n_loc, ...) shapes
+        with jax.named_scope("wssl.client"):
+            return (jnp.zeros((n_loc,), p.dtype).at[rows].set(p * ok),
+                    jax.tree.map(
+                        lambda a, g: jnp.zeros_like(a).at[rows].set(g),
+                        state.client_stack, gc))
+
+    def later_round():
+        return (l, *placed(), gs, ge)
+
+    def first_round():
+        # the other groups; shared-stage gradients accumulate in fp32
+        def one_group(j, carry):
+            lo, po, gco, gso, geo = carry
+            rj, okj = groups[j], live[j]
+            lj, pj, gcj, gsj, gej = block(rj, okj)
+            add = lambda a, g: a + g.astype(a.dtype)
+            with jax.named_scope("wssl.client"):
+                gco = jax.tree.map(lambda a, g: a.at[rj].add(g), gco, gcj)
+            return (lo + lj, po.at[rj].add(pj * okj), gco,
+                    jax.tree.map(add, gso, gsj),
+                    [jax.tree.map(add, a, g) for a, g in zip(geo, gej)])
+
+        f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
+        lo, po, gco, gso, geo = jax.lax.fori_loop(
+            1, passes, one_group,
+            (l, *placed(), f32(gs), [f32(g) for g in ge]))
+        cast = lambda t, ref: jax.tree.map(lambda a, r: a.astype(r.dtype),
+                                           t, ref)
+        return (lo, po, gco, cast(gso, gs),
+                [cast(a, r) for a, r in zip(geo, ge)])
+
+    out = jax.lax.cond(first, first_round, later_round)
+    work_rows = jnp.where(first, every, jnp.asarray(k * b, jnp.float32))
+    return tuple(out) + static["bytes"] + (work_rows,)
+
+
 def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
                val_batch: Optional[Dict[str, jax.Array]] = None,
                scenario: Optional["sim_faults.ScenarioParams"] = None,
@@ -542,8 +797,13 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
     penalty = None
     if wssl_cfg.select_staleness_beta and plan is not None:
         penalty = sim_faults.client_latencies(plan, n) - 1.0
+    # the k indices are drawn once: they pick the rows the split fwd/bwd
+    # runs on, and the mask is made from them
+    idx = wssl.weighted_sample(rng_sel, state.importance,
+                               wssl_cfg.num_selected(), penalty=penalty,
+                               beta=wssl_cfg.select_staleness_beta)
     mask = wssl.participation_mask(rng_sel, state.importance, wssl_cfg,
-                                   state.round_index, penalty=penalty)
+                                   state.round_index, idx=idx)
 
     # dropout ⇒ zero-mask (dropped clients compose like unselected ones)
     if plan is not None:
@@ -567,123 +827,12 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
     embeds = batch.get("embeds")
 
     # ---- Algorithm 2 steps 2-4: split fwd / chained N-phase backward ----
-    span = train_cfg.remat_span
-    chunk = train_cfg.client_chunk
-    if chunk is not None:
-        # client-chunked scan: O(chunk) activation peak, flat semantics
-        # (documented fp band — see _client_grads_chunked)
-        (loss, pcl, g_client, g_server, g_edges, hop_bytes,
-         act_wire_bytes) = _client_grads_chunked(
-            state.client_stack, state.edge_stages, state.server_params,
-            tokens, labels, embeds, agg_w_loc * mask_loc,
-            model_cfg=model_cfg, train_cfg=train_cfg, impl=impl,
-            chunk=chunk, n=n, n_loc=n_loc, ctx=ctx, comp_cfg=comp_cfg,
-            comp_p=comp_p, compress_acts=compress_acts, rng_sel=rng_sel)
-    else:
-        def client_fn(cstack):
-            def one(cp, toks, emb):
-                return tf.client_forward(cp, model_cfg, toks, embeds=emb,
-                                         impl=impl, remat=remat,
-                                         remat_span=span)
-            if embeds is not None:
-                return _client_vmap(one)(cstack, tokens, embeds)
-            return _client_vmap(lambda cp, t: one(cp, t, None))(cstack,
-                                                                tokens)
-
-        with jax.named_scope("wssl.client"):
-            acts, client_vjp = jax.vjp(client_fn, state.client_stack)
-        acts = shard_activation(acts, "client", None, None, None)
-        hop_bytes = [acts.size // acts.shape[0] * acts.dtype.itemsize]
-        act_wire_bytes = []
-        if compress_acts:
-            acts = compress_mod.compress_activations(
-                acts, jax.random.fold_in(rng_sel, 0xAC0), comp_cfg, comp_p)
-            act_wire_bytes.append(compress_mod.activation_wire_bytes(
-                acts.size // acts.shape[0] // acts.shape[-1],
-                acts.shape[-1], comp_cfg, comp_p))
-
-        # forward relay through the shared edge stages (per-client
-        # activations, shared params: vmap over the client axis with
-        # in_axes=None params).  Each edge stage also reports its MoE aux
-        # loss so the objective stays invariant to where the cuts sit.
-        x, edge_vjps = acts, []
-        edge_aux = jnp.zeros((), jnp.float32)
-        for j in range(num_edges):
-            def edge_fn(p, a, j=j):
-                return _client_vmap(
-                    lambda pi, ai: tf.stage_forward(pi, model_cfg, ai,
-                                                    j + 1, impl=impl,
-                                                    remat=remat,
-                                                    remat_span=span,
-                                                    with_aux=True),
-                    in_axes=(None, 0))(p, a)
-            (x, aux_j), vjp = jax.vjp(edge_fn, state.edge_stages[j], x)
-            x = shard_activation(x, "client", None, None, None)
-            # aux_j.mean() is the mean over the clients in view; with a
-            # ctx that view is local, so psum/S completes the global mean
-            # exactly (equal shard sizes)
-            edge_aux = edge_aux + (
-                _psum(aux_j.mean(), ctx) / ctx.num_shards
-                if ctx is not None else aux_j.mean())
-            edge_vjps.append(vjp)
-            hop_bytes.append(x.size // x.shape[0] * x.dtype.itemsize)
-            if compress_acts:
-                x = compress_mod.compress_activations(
-                    x, jax.random.fold_in(rng_sel, 0xAC1 + j), comp_cfg,
-                    comp_p)
-                act_wire_bytes.append(compress_mod.activation_wire_bytes(
-                    x.size // x.shape[0] // x.shape[-1], x.shape[-1],
-                    comp_cfg, comp_p))
-
-        def server_loss(sp, a):
-            losses, aux = _per_client_losses(model_cfg, sp, a, labels,
-                                             impl, remat, span)
-            local = jnp.sum(agg_w_loc * mask_loc * losses)
-            if ctx is not None:
-                # the CE term sums over all clients; the MoE aux is a mean
-                # over clients, so psum of per-shard means / S completes it
-                total = (jax.lax.psum(local, ctx.axis)
-                         + jax.lax.psum(aux, ctx.axis) / ctx.num_shards)
-            else:
-                total = local + aux
-            return total, losses
-
-        with jax.named_scope("wssl.server"):
-            (loss, pcl), (g_server, g_x) = jax.value_and_grad(
-                server_loss, argnums=(0, 1), has_aux=True)(
-                    state.server_params, x)
-        loss = loss + edge_aux
-        # with a ctx the vjp ran per shard on a replicated server stage —
-        # each shard's g_server carries only its local clients'
-        # contribution; the psum completes the global gradient (and keeps
-        # it replicated)
-        g_server = _psum(g_server, ctx)
-
-        # backward relay: inject each hop's cotangent upstream (the
-        # mean-aux term contributes 1/N per client alongside the
-        # activation cotangent)
-        if compress_acts:
-            # down-hop wire compression: the returned server→edge gradient
-            # is itself a (N, b, s, d) activation-shaped tensor; chaining
-            # the lossy reconstruction into the manual vjp relay makes the
-            # backward a straight-through estimate of the compressed
-            # forward
-            g_x = compress_mod.compress_activations(
-                g_x, jax.random.fold_in(rng_sel, 0xDC0 + num_edges),
-                comp_cfg, comp_p)
-        aux_ct = jnp.full((n_loc,), 1.0 / n, jnp.float32)
-        g_edges = []
-        for back_j, vjp in enumerate(reversed(edge_vjps)):
-            g_e, g_x = vjp((g_x, aux_ct))
-            if compress_acts:
-                g_x = compress_mod.compress_activations(
-                    g_x, jax.random.fold_in(rng_sel,
-                                            0xDC0 + num_edges - 1 - back_j),
-                    comp_cfg, comp_p)
-            g_edges.append(_psum(g_e, ctx))
-        g_edges.reverse()
-        with jax.named_scope("wssl.client"):
-            (g_client,) = client_vjp(g_x)
+    (loss, pcl, g_client, g_server, g_edges, hop_bytes, act_wire_bytes,
+     work_rows) = _split_grads(
+        state, tokens, labels, embeds, agg_w_loc * mask_loc, idx,
+        model_cfg=model_cfg, wssl_cfg=wssl_cfg, train_cfg=train_cfg,
+        impl=impl, n=n, n_loc=n_loc, ctx=ctx, comp_cfg=comp_cfg,
+        comp_p=comp_p, compress_acts=compress_acts, rng_sel=rng_sel)
 
     # ---- optimizer: clip, step (masked for unselected clients), faults ---
     with jax.named_scope("wssl.optimizer"):
@@ -764,8 +913,9 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
                                          impl=impl, remat=remat)
                 return loss
 
-            if chunk is not None:
-                vl_loc = _chunked_client_map(val_one, new_cstack, chunk)
+            if train_cfg.client_chunk is not None:
+                vl_loc = _chunked_client_map(val_one, new_cstack,
+                                             train_cfg.client_chunk)
             else:
                 vl_loc = _client_vmap(val_one)(new_cstack)
             val_losses = _gather(vl_loc, ctx)
@@ -854,6 +1004,7 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
         bytes_update_comp=update_comp,
         bytes_cross_shard=cross, bytes_intra_shard=intra,
         bytes_act_raw=act_raw, bytes_act_comp=act_comp,
+        work_rows=work_rows,
     )
     new_state = WSSLState(
         client_stack=new_cstack, server_params=new_server,

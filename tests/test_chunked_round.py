@@ -9,12 +9,16 @@ Contracts:
   reduction; measured max leaf diff ~7e-7 on the tiny config, asserted
   at 1e-4 — docs/scaling.md tolerance table);
 * ``client_chunk == n`` is ONE chunk covering every client — the same
-  reduction order as flat, so bit-for-bit equal;
+  reduction order as flat, so bit-for-bit equal (the whole round with
+  every client selected, and the block alone against the flat block);
 * ``client_chunk=None`` keeps the flat trace bit-for-bit (covered by
   the goldens in test_round_regression.py, which run with the default);
 * a chunk that does not divide the per-shard client count raises at
   trace time, and config validation rejects nonsensical knobs.
 """
+
+import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -23,9 +27,12 @@ import pytest
 
 from repro.config import (AsyncRoundsConfig, ModelConfig, TrainConfig,
                           WSSLConfig)
+from repro.core import wssl
 from repro.core.async_round import (async_params, init_async_state,
                                     make_async_round_fn)
-from repro.core.round import init_state, make_round_fn, make_sharded_round_fn
+from repro.core.round import (_client_grads_chunked, _client_grads_flat,
+                              init_state, make_round_fn,
+                              make_sharded_round_fn)
 from repro.data.synthetic import lm_batch
 
 TINY = ModelConfig(name="tiny-chunk", num_layers=2, d_model=32, num_heads=2,
@@ -58,11 +65,11 @@ def _batches(rounds=2):
     return val, out
 
 
-def _run_sync(chunk):
-    val, batches = _batches()
+def _run_sync(chunk, rounds=2, w=W):
+    val, batches = _batches(rounds)
     t = _t(chunk)
-    state, _ = init_state(jax.random.PRNGKey(0), TINY, W, t)
-    rf = jax.jit(make_round_fn(TINY, W, t, impl="dense"))
+    state, _ = init_state(jax.random.PRNGKey(0), TINY, w, t)
+    rf = jax.jit(make_round_fn(TINY, w, t, impl="dense"))
     for b in batches:
         state, m = rf(state, b, val)
     return state, m
@@ -109,11 +116,41 @@ def test_chunked_matches_flat_async(chunk):
 
 def test_single_chunk_is_bit_for_bit():
     """chunk == n: one scan step over all clients — identical reduction
-    order to the flat trace, so every leaf and metric is bit-equal."""
-    s_f, m_f = _run_sync(None)
-    s_c, m_c = _run_sync(N)
+    order to the flat trace, so every leaf and metric is bit-equal.  With
+    every client selected both rounds run the masked block over all N
+    rows (a flat round with k < N runs the block on k gathered clients'
+    rows at a time: test_single_chunk_block_is_bit_for_bit and
+    tests/test_selected_rows.py cover that)."""
+    w = dataclasses.replace(W, participation_fraction=1.0)
+    s_f, m_f = _run_sync(None, w=w)
+    s_c, m_c = _run_sync(N, w=w)
     for a, b in zip(jax.tree.leaves((s_c, m_c)), jax.tree.leaves((s_f, m_f))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_single_chunk_block_is_bit_for_bit():
+    """chunk == n against the flat block over all clients: every
+    gradient, loss and byte count is bit-equal, with every client
+    weighted (round 0) and with k of them (the masked path of later
+    rounds)."""
+    s_f, _ = _run_sync(None, rounds=1)
+    _, batches = _batches()
+    kw = dict(model_cfg=TINY, train_cfg=_t(N), impl="dense", n=N,
+              ctx=None, comp_cfg=W.compression, comp_p=None,
+              compress_acts=False, rng_sel=jax.random.PRNGKey(1))
+    flat = jax.jit(functools.partial(_client_grads_flat, **kw))
+    one = jax.jit(functools.partial(_client_grads_chunked, chunk=N, n_loc=N,
+                                    **kw))
+    for mask in (jnp.ones((N,), jnp.float32),
+                 wssl.selection_mask(jnp.asarray([1, 4, 5, 6]), N)):
+        coef = wssl.aggregation_weights(s_f.importance, mask, W) * mask
+        args = (s_f.client_stack, s_f.edge_stages, s_f.server_params,
+                batches[1]["tokens"], batches[1]["labels"], None, coef)
+        got, want = one(*args), flat(*args)
+        assert got[5:] == want[5:]
+        for a, b in zip(jax.tree.leaves(got[:5]),
+                        jax.tree.leaves(want[:5])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_chunk_must_divide_clients():
