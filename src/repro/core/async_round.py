@@ -52,10 +52,9 @@ from repro import compress as compress_mod
 from repro.core import aggregation, wssl
 from repro.core.protocol import hierarchical_sync_bytes, sync_round_bytes
 from repro.core.round import (RoundMetrics, ShardCtx, WSSLState,
-                              _chunked_client_map, _client_stage_bytes,
-                              _client_vmap, _gather, _loc, _local_plan,
-                              _opt_kwargs, _split_grads)
-from repro.models import transformer as tf
+                              _client_stage_bytes, _gather, _loc,
+                              _local_plan, _opt_kwargs, _split_grads,
+                              _validate)
 from repro.optim import clip_by_global_norm, make_optimizer
 from repro.sim import faults as sim_faults
 from repro.sharding import shard_activation
@@ -161,7 +160,6 @@ def async_wssl_round(state: WSSLState, astate: AsyncState,
     ctx = shard_ctx
     n = wssl_cfg.num_clients
     n_loc = n // ctx.num_shards if ctx is not None else n
-    remat = train_cfg.remat
     num_edges = len(state.edge_stages)
     kind = wssl_cfg.async_rounds.staleness_weighting
     if async_p is None:
@@ -312,29 +310,13 @@ def async_wssl_round(state: WSSLState, astate: AsyncState,
                       for no, oo in zip(new_opt_e, state.opt_edge))
 
     # ---- validation on the server-held ζ → importance ------------------
-    if val_batch is not None:
-        vt, vl = val_batch["tokens"], val_batch["labels"]
-
-        def val_one(cp):
-            a = tf.client_forward(cp, model_cfg, vt, impl=impl, remat=remat)
-            for j in range(num_edges):
-                a = tf.stage_forward(new_edges[j], model_cfg, a, j + 1,
-                                     impl=impl, remat=remat)
-            loss, _ = tf.server_loss(new_server, model_cfg, a, vl,
-                                     impl=impl, remat=remat)
-            return loss
-
-        if train_cfg.client_chunk is not None:
-            vl_loc = _chunked_client_map(val_one, new_cstack,
-                                         train_cfg.client_chunk)
-        else:
-            vl_loc = _client_vmap(val_one)(new_cstack)
-        val_losses = _gather(vl_loc, ctx)
-        importance = wssl.compute_importance(val_losses, wssl_cfg,
-                                             prev=state.importance)
-    else:
-        val_losses = jnp.zeros((n,), jnp.float32)
-        importance = state.importance
+    val_losses, val_rows = _validate(
+        new_cstack, new_edges, new_server, val_batch, idx, state.round_index,
+        model_cfg=model_cfg, train_cfg=train_cfg, impl=impl, n=n, ctx=ctx,
+        faults=plan is not None)
+    importance = (state.importance if val_batch is None else
+                  wssl.compute_importance(val_losses, wssl_cfg,
+                                          prev=state.importance))
 
     # ---- stale-update delivery + weighted aggregation --------------------
     # an arriving client applies its parked delta to the *current* global
@@ -450,7 +432,7 @@ def async_wssl_round(state: WSSLState, astate: AsyncState,
         bytes_update_comp=update_comp,
         bytes_cross_shard=cross, bytes_intra_shard=intra,
         bytes_act_raw=act_raw, bytes_act_comp=act_comp,
-        work_rows=work_rows,
+        work_rows=work_rows, val_rows=val_rows,
     )
     amet = AsyncRoundMetrics(
         base=metrics,

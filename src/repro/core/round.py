@@ -24,6 +24,9 @@ would change the objective or need routing — the sharded and
 client-chunked rounds, activation compression, MoE models — unselected
 clients are *masked* instead: they run with loss weight 0 and their exact
 zero gradients are ignored by the masked optimizer (docs/scaling.md).
+Validation likewise scores k+1 client stages after round 0, the k
+selected and one of the unselected clients, which all hold the global
+stage (``_validate``).
 """
 
 from __future__ import annotations
@@ -135,6 +138,10 @@ class RoundMetrics(NamedTuple):
     # client rows whose split forward and backward ran this round: k·b
     # where only the selected clients run, N·b where all are masked
     work_rows: jax.Array = 0.0
+    # validation rows of the distinct clients the forward ran on: (k+1)·bv
+    # where the unselected clients share one scored stage, N·bv where all
+    # are scored (``_validate``)
+    val_rows: jax.Array = 0.0
 
 
 def init_state(rng, model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
@@ -717,6 +724,83 @@ def _split_grads(state: WSSLState, tokens, labels, embeds,
     return tuple(out) + static["bytes"] + (work_rows,)
 
 
+def _validate(new_cstack: Params, new_edges: Tuple[Params, ...],
+              new_server: Params, val_batch: Optional[Dict[str, jax.Array]],
+              idx: jax.Array, round_index: jax.Array, *,
+              model_cfg: ModelConfig, train_cfg: TrainConfig, impl: str,
+              n: int, ctx: Optional[ShardCtx], faults: bool
+              ) -> Tuple[jax.Array, jax.Array]:
+    """Each client's loss on the server-held ζ, for both rounds ->
+    ``((N,) val_losses, val_rows)``: client stage, edge relay and server
+    loss per client stage of ``new_cstack``; ``val_rows`` counts the
+    validation rows of the distinct clients the forward ran on (zeros and
+    0 without ``val_batch``).
+
+    Precondition: every client holds the same stage at the start of the
+    round, which ``init_state`` (identical copies) and ``broadcast_global``
+    (the end of every round) guarantee.  A flat round without faults then
+    changes only the rows it stepped — the masked optimizer leaves every
+    row off ``idx`` bit for bit — so after round 0 the N-k unselected
+    clients hold one stage, the global one.  There the forward runs on
+    k+1 rows: the k selected and the first unselected client ``j``, whose
+    loss every unselected client gets.  Round 0 steps every client and
+    scores all N in blocks of k+1 (the last padded with clients already
+    covered), through the same loop, so the forward is traced once and
+    held at one call site.  ``client_chunk`` caps the block at ``chunk``
+    rows.
+
+    All N rows are scored where the precondition may fail or nothing is
+    saved: a sharded round or a mesh that shards the client axis, a fault
+    scenario (adaptive adversaries rewrite unselected rows, and a
+    non-finite corrupted gradient breaks the masked blend), and k+1 >= N.
+    """
+    if val_batch is None:
+        return jnp.zeros((n,), jnp.float32), jnp.zeros((), jnp.float32)
+    vt, vl = val_batch["tokens"], val_batch["labels"]
+    remat = train_cfg.remat
+    chunk = train_cfg.client_chunk
+    bv = vt.shape[0]
+    k = idx.shape[0]
+
+    def val_one(cp):
+        a = tf.client_forward(cp, model_cfg, vt, impl=impl, remat=remat)
+        for j in range(len(new_edges)):
+            a = tf.stage_forward(new_edges[j], model_cfg, a, j + 1,
+                                 impl=impl, remat=remat)
+        loss, _ = tf.server_loss(new_server, model_cfg, a, vl, impl=impl,
+                                 remat=remat)
+        return loss
+
+    if (ctx is not None or faults or _client_spmd_axes() is not None
+            or k + 1 >= n):
+        if chunk is not None:
+            vl_loc = _chunked_client_map(val_one, new_cstack, chunk)
+        else:
+            vl_loc = _client_vmap(val_one)(new_cstack)
+        return _gather(vl_loc, ctx), jnp.asarray(n * bv, jnp.float32)
+
+    m = k + 1 if chunk is None else min(k + 1, chunk)
+    passes = -(-n // m)
+    first = round_index == 0
+    selected = wssl.selection_mask(idx, n) > 0
+    j = jnp.argmin(selected)
+    rows = jnp.concatenate([idx, j[None]])
+    seats = jnp.arange(passes * m)
+    groups = jnp.where(first, seats % n, rows[seats % (k + 1)])
+    groups = groups.reshape(passes, m)
+
+    def block(i, losses):
+        g = groups[i]
+        cs = jax.tree.map(lambda a: jnp.take(a, g, axis=0), new_cstack)
+        return losses.at[g].set(_client_vmap(val_one)(cs))
+
+    trips = jnp.where(first, passes, -(-(k + 1) // m))
+    losses = jax.lax.fori_loop(0, trips, block, jnp.zeros((n,), jnp.float32))
+    val_losses = jnp.where(first | selected, losses, losses[j])
+    val_rows = jnp.where(first, n * bv, (k + 1) * bv).astype(jnp.float32)
+    return val_losses, val_rows
+
+
 def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
                val_batch: Optional[Dict[str, jax.Array]] = None,
                scenario: Optional["sim_faults.ScenarioParams"] = None,
@@ -772,7 +856,6 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
     ctx = shard_ctx
     n = wssl_cfg.num_clients
     n_loc = n // ctx.num_shards if ctx is not None else n
-    remat = train_cfg.remat
     num_edges = len(state.edge_stages)
     rng, rng_sel = jax.random.split(state.rng)
     comp_cfg = wssl_cfg.compression
@@ -899,31 +982,14 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
         new_opt_e = tuple(new_opt_e)
 
     # ---- validation on the server-held ζ → importance ------------------
-    if val_batch is not None:
-        with jax.named_scope("wssl.validate"):
-            vt, vl = val_batch["tokens"], val_batch["labels"]
-
-            def val_one(cp):
-                a = tf.client_forward(cp, model_cfg, vt, impl=impl,
-                                      remat=remat)
-                for j in range(num_edges):
-                    a = tf.stage_forward(new_edges[j], model_cfg, a, j + 1,
-                                         impl=impl, remat=remat)
-                loss, _ = tf.server_loss(new_server, model_cfg, a, vl,
-                                         impl=impl, remat=remat)
-                return loss
-
-            if train_cfg.client_chunk is not None:
-                vl_loc = _chunked_client_map(val_one, new_cstack,
-                                             train_cfg.client_chunk)
-            else:
-                vl_loc = _client_vmap(val_one)(new_cstack)
-            val_losses = _gather(vl_loc, ctx)
-            importance = wssl.compute_importance(val_losses, wssl_cfg,
-                                                 prev=state.importance)
-    else:
-        val_losses = jnp.zeros((n,), jnp.float32)
-        importance = state.importance
+    with jax.named_scope("wssl.validate"):
+        val_losses, val_rows = _validate(
+            new_cstack, new_edges, new_server, val_batch, idx,
+            state.round_index, model_cfg=model_cfg, train_cfg=train_cfg,
+            impl=impl, n=n, ctx=ctx, faults=plan is not None)
+        importance = (state.importance if val_batch is None else
+                      wssl.compute_importance(val_losses, wssl_cfg,
+                                              prev=state.importance))
 
     # ---- update-path compression (repro.compress) -----------------------
     # the *sent* stage delta is compressed client-side; the server
@@ -1004,7 +1070,7 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
         bytes_update_comp=update_comp,
         bytes_cross_shard=cross, bytes_intra_shard=intra,
         bytes_act_raw=act_raw, bytes_act_comp=act_comp,
-        work_rows=work_rows,
+        work_rows=work_rows, val_rows=val_rows,
     )
     new_state = WSSLState(
         client_stack=new_cstack, server_params=new_server,

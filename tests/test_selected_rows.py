@@ -13,6 +13,18 @@ label-flipped plus dropped-out clients.  ``RoundMetrics.work_rows``
 counts the rows that ran: k·b on rounds after the first, N·b on round 0
 and wherever the masked path stays (sharded, client-chunked, activation
 compression, MoE).
+
+Validation scores k+1 client stages after round 0, not N: the k selected
+and one unselected client, whose loss the other unselected clients share
+(``core/round.py::_validate``).  Its losses and importance must match the
+N-row vmap over the stack the round scored (read before
+``broadcast_global`` resets it), for the sync and the async round under
+AdamW and SGD.
+``RoundMetrics.val_rows`` counts the scored rows: (k+1)·bv after round 0,
+N·bv in round 0 and wherever every row is scored (sharded, a fault
+scenario, k+1 >= N).  The shortcut rests on the unselected clients'
+stages leaving a clean round unchanged, which an adaptive adversary
+breaks.
 """
 
 import dataclasses
@@ -28,8 +40,10 @@ from test_round_regression import LEAF_ATOL, METRIC_RTOL
 from repro.config import (CompressionConfig, ModelConfig, Scenario,
                           TrainConfig, WSSLConfig, get_arch, reduced)
 from repro.core import wssl
-from repro.core.round import (_client_grads_flat, _split_grads, init_state,
-                              make_round_fn, make_sharded_round_fn)
+from repro.core.async_round import init_async_state, make_async_round_fn
+from repro.core.round import (_client_grads_flat, _split_grads, _validate,
+                              init_state, make_round_fn,
+                              make_sharded_round_fn)
 from repro.data.synthetic import lm_batch
 from repro.sim import faults as sim_faults
 
@@ -41,6 +55,7 @@ VISION = reduced(get_arch("qwen2-vl-72b")).replace(
 T = TrainConfig(remat=False, learning_rate=1e-3, warmup_steps=0,
                 schedule="constant")
 N, B, S = 4, 2, 16
+BV = 2
 IDX = (2, 0)
 
 CASES = {
@@ -125,7 +140,84 @@ def test_selected_rows_match_the_masked_path(case, round_index):
                for g in jax.tree.leaves(rows[2]))
 
 
-def _work_rows(model, w, t, rounds=2, sharded=False):
+def _val(model):
+    d = lm_batch(BV, S, model.vocab_size, seed=999)
+    return {"tokens": jnp.asarray(d["tokens"]),
+            "labels": jnp.asarray(d["labels"])}
+
+
+def _round(kind, model, w, t):
+    """A jitted sync or async round -> ``run(state, batch, val, scenario)
+    -> (state, RoundMetrics)``; the async round at deadline = inf."""
+    if kind == "sync":
+        return jax.jit(make_round_fn(model, w, t, impl="dense"))
+    f = jax.jit(make_async_round_fn(model, w, t, impl="dense"))
+
+    def run(state, batch, val, scenario):
+        new, _, m = f(state, init_async_state(state), batch, val, scenario)
+        return new, m.base
+    return run
+
+
+# the CASES whose rounds run without faults, and a client-chunked round
+SCORED = {name: (model, wkw, {}, n)
+          for name, (model, wkw, faulty, n) in CASES.items() if not faulty}
+SCORED["client_chunk"] = (TINY, {}, {"client_chunk": 2}, N)
+
+
+@functools.lru_cache(maxsize=None)
+def _scored_round(case, kind, optimizer):
+    """One jitted round per case, shared by both round indices (a larger
+    step than ``T``'s, so that stepped clients score well apart), and the
+    validation that scores every row."""
+    model, wkw, tkw, n = SCORED[case]
+    w = WSSLConfig(num_clients=n, participation_fraction=0.5, **wkw)
+    t = dataclasses.replace(T, optimizer=optimizer, learning_rate=1e-2,
+                            **tkw)
+    every = jax.jit(functools.partial(
+        _validate, idx=jnp.arange(w.num_selected()), round_index=0,
+        model_cfg=model, train_cfg=t, impl="dense", n=n, ctx=None,
+        faults=True))
+    return model, w, t, _round(kind, model, w, t), every
+
+
+@pytest.mark.parametrize("optimizer", ["adamw", "sgd"])
+@pytest.mark.parametrize("kind", ["sync", "async"])
+@pytest.mark.parametrize("round_index", [1, 0])
+@pytest.mark.parametrize("case", list(SCORED))
+def test_validation_on_k_plus_one_rows_matches_every_row(
+        case, round_index, kind, optimizer, monkeypatch):
+    # the round returns the stack it scored, before the reset to the
+    # global stage
+    monkeypatch.setattr(wssl, "broadcast_global", lambda stack, g: stack)
+    model, w, t, run, every = _scored_round(case, kind, optimizer)
+    n, k = w.num_clients, w.num_selected()
+    state, _ = init_state(jax.random.PRNGKey(0), model, w, t)
+    val = _val(model)
+    if round_index:
+        state, _ = run(state, _batch(model, seed=0, n=n), val, None)
+        # every client on one stage, as broadcast_global leaves them
+        state = state._replace(client_stack=jax.tree.map(
+            lambda a: jnp.broadcast_to(a[:1], a.shape), state.client_stack))
+    new, got = run(state, _batch(model, seed=1, n=n), val, None)
+    want, rows = every(new.client_stack, new.edge_stages, new.server_params,
+                       val)
+    assert float(got.val_rows) == (k + 1 if round_index else n) * BV
+    assert float(rows) == n * BV
+    _close(got.val_loss, want, "val_loss")
+    _close(got.importance,
+           wssl.compute_importance(want, w, prev=state.importance),
+           "importance")
+    # the stepped clients score apart from the ones that share a stage
+    vl, sel = np.asarray(want), np.asarray(got.mask) > 0
+    if round_index:
+        assert np.all(vl[~sel] == vl[~sel][0])
+        gap = np.abs(vl[sel] - vl[~sel][0]).min() / abs(vl[~sel][0])
+        assert gap > 4 * METRIC_RTOL, gap
+
+
+def _work_rows(model, w, t, rounds=2, sharded=False, val=None,
+               scenario=None, counter="work_rows"):
     state, _ = init_state(jax.random.PRNGKey(0), model, w, t)
     if sharded:
         from repro.launch.mesh import make_client_mesh
@@ -139,8 +231,8 @@ def _work_rows(model, w, t, rounds=2, sharded=False):
         batch = _batch(model, seed=r, n=w.num_clients)
         if sharded:
             batch = rf.place_batch(batch)
-        state, m = rf(state, batch)
-        out.append(float(m.work_rows))
+        state, m = rf(state, batch, val, scenario)
+        out.append(float(getattr(m, counter)))
     return out
 
 
@@ -165,3 +257,55 @@ def test_work_rows_on_the_masked_path(case):
     w = WSSLConfig(num_clients=N, participation_fraction=0.5, **wkw)
     t = dataclasses.replace(T, **tkw)
     assert _work_rows(model, w, t, sharded=sharded) == [N * B, N * B]
+
+
+# participation, TrainConfig changes, scenario, sharded -> clients scored
+# in rounds 0, 1, 2
+VAL_ROWS = {
+    "flat": (0.5, {}, None, False, [N, 3, 3]),
+    "client_chunk": (0.5, {"client_chunk": 2}, None, False, [N, 3, 3]),
+    "sharded": (0.5, {}, None, True, [N, N, N]),
+    "scenario": (0.5, {}, Scenario(), False, [N, N, N]),
+    "k3_of_4": (0.75, {}, None, False, [N, N, N]),
+}
+
+
+@pytest.mark.parametrize("case", list(VAL_ROWS))
+def test_val_rows_counts_the_scored_clients(case):
+    frac, tkw, sc, sharded, clients = VAL_ROWS[case]
+    w = WSSLConfig(num_clients=N, participation_fraction=frac)
+    t = dataclasses.replace(T, **tkw)
+    sc = None if sc is None else sim_faults.scenario_params(sc)
+    got = _work_rows(TINY, w, t, rounds=3, sharded=sharded, val=_val(TINY),
+                     scenario=sc, counter="val_rows")
+    assert got == [c * BV for c in clients]
+
+
+@pytest.mark.parametrize("optimizer", ["adamw", "sgd"])
+@pytest.mark.parametrize("adaptive", [False, True], ids=["clean", "adaptive"])
+def test_unselected_rows_keep_their_stage(adaptive, optimizer, monkeypatch):
+    """The precondition of the k+1-row validation: after a clean round
+    every client stage off the selection is bit for bit the one it
+    started from (read before ``broadcast_global`` resets the stack).
+    Adaptive adversaries (clients 0 and 1 here, left unselected) rewrite
+    their rows whether selected or not, which is why a round with a fault
+    scenario scores every row."""
+    monkeypatch.setattr(wssl, "broadcast_global", lambda stack, g: stack)
+    w = WSSLConfig(num_clients=N, participation_fraction=0.5)
+    t = dataclasses.replace(T, optimizer=optimizer)
+    state, _ = init_state(jax.random.PRNGKey(0), TINY, w, t)
+    rf = jax.jit(make_round_fn(TINY, w, t, impl="dense"))
+    state, _ = rf(state, _batch(TINY, seed=0), None, None)
+    # round 1 from the stepped stack, with clients 2 and 3 drawn
+    state = state._replace(
+        importance=jnp.asarray([1e-9, 1e-9, 0.5, 0.5], jnp.float32))
+    sc = (sim_faults.scenario_params(Scenario(name="adaptive",
+                                              adaptive_fraction=0.5))
+          if adaptive else None)
+    new, m = rf(state, _batch(TINY, seed=1), None, sc)
+    off = np.asarray(m.mask) == 0
+    assert off.tolist() == [True, True, False, False]
+    same = [np.array_equal(np.asarray(a)[off], np.asarray(b)[off])
+            for a, b in zip(jax.tree.leaves(new.client_stack),
+                            jax.tree.leaves(state.client_stack))]
+    assert not all(same) if adaptive else all(same)
