@@ -160,8 +160,7 @@ def apply_compression(delta: Params, residual: Params, mask: jax.Array,
     return sent, new_res
 
 
-def compressed_stage_bytes(client_stack: Params, n: int,
-                           cfg: CompressionConfig,
+def compressed_stage_bytes(client_stack: Params, cfg: CompressionConfig,
                            params: Optional[CompressionParams] = None):
     """Traced wire bytes of ONE client's compressed stage upload.
 
@@ -173,9 +172,9 @@ def compressed_stage_bytes(client_stack: Params, n: int,
     kind = cfg.kind
     total = jnp.zeros((), jnp.float32)
     for l in jax.tree.leaves(client_stack):
-        # per-client elements from the leaf's own leading axis, NOT from
-        # ``n``: in the sharded round the stack holds n/shards clients
-        # while ``n`` stays global, and bytes are per client either way
+        # per-client elements from the leaf's own leading axis: in the
+        # sharded round the stack holds N/S clients, and bytes are per
+        # client either way
         m = l.size // l.shape[0]
         if m == 0:
             continue

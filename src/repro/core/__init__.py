@@ -7,10 +7,10 @@
                 uniform / trimmed_mean / median / krum / multi_krum) every
                 round variant dispatches Algorithm 2 step 5 through.
 * split.py    — the two-phase split fwd/bwd protocol (≡ end-to-end grad).
-* round.py    — one fused WSSL communication round for the transformer stack.
+* round.py    — one fused WSSL round body for the transformer stack.
 * async_round.py — the bounded-staleness variant: round deadline,
-                stale-update buffer, staleness-weighted aggregation
-                (deadline=inf ≡ round.py, bit-for-bit).
+                stale-update buffer, staleness-weighted aggregation, as
+                steps added to round.py's body (deadline=inf ≡ sync).
 * paper_loop.py — paper-scale WSSL trainer (gait FFN / ResNet-18).
 * protocol.py — communication accounting (incl. staleness columns).
 * fairness.py — participation / accuracy fairness metrics.

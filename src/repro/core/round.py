@@ -1,7 +1,9 @@
 """One fused WSSL communication round for the transformer stack.
 
-All of Algorithm 1 + Algorithm 2 as a single jit-able function over a fixed
-client axis, generalized to an N-stage split pipeline:
+All of Algorithm 1 + Algorithm 2 as one jit-able body over a fixed client
+axis (``_round``, which the bounded-staleness round of ``async_round.py``
+shares and adds its own steps to), generalized to an N-stage split
+pipeline:
 
   importance → Gumbel-top-k selection mask → per-client split forward /
   chained N-phase backward (client stages vmapped over the stacked client
@@ -32,6 +34,8 @@ stage (``_validate``).
 from __future__ import annotations
 
 import functools
+import inspect
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -43,10 +47,11 @@ from repro.core import aggregation, wssl
 from repro.core.protocol import hierarchical_sync_bytes, sync_round_bytes
 from repro.models import transformer as tf
 from repro.sim import faults as sim_faults
-from repro.optim import clip_by_global_norm, make_optimizer
+from repro.optim import clip_by_global_norm, make_optimizer, make_schedule
 from repro.sharding import bound_axes, current_mesh, shard_activation
 
 Params = Any
+_pc = sim_faults._per_client    # (N,) vector against a (N, ...) leaf
 
 
 class ShardCtx(NamedTuple):
@@ -269,12 +274,12 @@ def _per_client_losses(cfg: ModelConfig, server_params: Params,
     return losses, auxes.mean()
 
 
-def _client_stage_bytes(client_stack: Params, n: int = 0) -> int:
+def _client_stage_bytes(client_stack: Params) -> int:
     """Static: bytes of ONE client's stage (the sync/aggregation payload).
 
-    Reads the stacked-client count off the leading leaf dim (``n`` is kept
-    for call-site compat but unused) so local (N/S, ...) shard stacks and
-    full (N, ...) stacks both report the same per-client payload."""
+    Reads the stacked-client count off the leading leaf dim, so local
+    (N/S, ...) shard stacks and full (N, ...) stacks both report the same
+    per-client payload."""
     return sum((l.size // l.shape[0]) * l.dtype.itemsize
                for l in jax.tree.leaves(client_stack))
 
@@ -801,6 +806,372 @@ def _validate(new_cstack: Params, new_edges: Tuple[Params, ...],
     return val_losses, val_rows
 
 
+def _admit(mask: jax.Array, plan, astate, async_p, n: int
+           ) -> Dict[str, jax.Array]:
+    """Deadline admission -> (N,) float vectors, full and replicated:
+    ``part`` (this round's fresh work), ``on_time``, ``admit``,
+    ``evicted``, ``arriving`` and ``delay``.
+
+    A client's latency (``sim_faults.client_latencies``) sets the rounds of
+    delay before its update can land (0 = on time, as every client at
+    deadline = inf).  Busy clients (``pending > 0``) take no fresh work.
+    A late client is evicted at admission if its delay reaches
+    ``max_staleness`` or it would overflow ``buffer_size``; arrivals
+    (``pending == 1``) free their slot first."""
+    lat = sim_faults.client_latencies(plan, n)
+    delay = jnp.maximum(jnp.ceil(lat / async_p.deadline) - 1.0, 0.0)
+    arriving = (astate.pending == 1).astype(jnp.float32)
+    mask = mask * (astate.pending == 0).astype(jnp.float32)
+    on_time = mask * (delay == 0)
+    late = mask * (delay > 0)
+    evict_late = late * (delay >= async_p.max_staleness)
+    admit = late - evict_late
+    slots = (astate.pending > 1).sum().astype(jnp.float32)
+    order = jnp.cumsum(admit) - admit     # admitted strictly before i
+    overflow = admit * ((slots + order) >= async_p.buffer_size)
+    admit = admit - overflow
+    return dict(evicted=evict_late + overflow, part=on_time + admit,
+                on_time=on_time, admit=admit, arriving=arriving, delay=delay)
+
+
+def _deliver(new_cstack: Params, old_cstack: Params, astate, adm, async_p,
+             kind: str, ctx: Optional[ShardCtx], n_loc: int):
+    """Stale-update delivery -> (the stack to aggregate, (N,) weights, their
+    local view).  An arriving client applies its parked delta to the
+    *current* global stage; its weight carries the staleness discount
+    (``wssl.async_contribution``), fused into the aggregation."""
+    contrib = wssl.async_contribution(adm["on_time"], adm["arriving"],
+                                      astate.staleness, async_p.max_staleness,
+                                      kind=kind, alpha=async_p.staleness_alpha)
+    contrib_loc = _loc(contrib, ctx, n_loc)
+
+    def one(new, old, buf):
+        arr = _pc(adm["arriving_loc"], new) > 0
+        stale = (old.astype(jnp.float32)
+                 + buf.astype(jnp.float32)).astype(new.dtype)
+        return jnp.where(arr, stale, new)
+
+    return (jax.tree.map(one, new_cstack, old_cstack, astate.buffer),
+            contrib, contrib_loc)
+
+
+def _park(presync_cstack: Params, old_cstack: Params, astate, adm):
+    """The new ``(pending, staleness, buffer)``.  An admitted client parks
+    its update for ``delay`` rounds, measured on the *pre-sync* stack (the
+    local update it computed, before ``broadcast_global``); a slot whose
+    update is still in flight keeps it; every other slot clears."""
+    admit, pending = adm["admit"], astate.pending
+
+    def one(new, old, buf):
+        delta = (new.astype(jnp.float32)
+                 - old.astype(jnp.float32)).astype(buf.dtype)
+        keep = _pc((adm["pending_loc"] > 1).astype(jnp.float32), buf) > 0
+        parked = jnp.where(keep, buf, jnp.zeros_like(buf))
+        return jnp.where(_pc(adm["admit_loc"], buf) > 0, delta, parked)
+
+    buffer = jax.tree.map(one, presync_cstack, old_cstack, astate.buffer)
+    d_i32 = adm["delay"].astype(jnp.int32)
+    new_pending = jnp.where(admit > 0, d_i32, jnp.maximum(pending - 1, 0))
+    staleness = jnp.where(admit > 0, d_i32,
+                          jnp.where(pending > 1, astate.staleness, 0))
+    return new_pending, staleness, buffer
+
+
+def _round(state: WSSLState, batch, val_batch, scenario, agg_p, comp_p, *,
+           model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
+           train_cfg: TrainConfig, schedule, impl: str,
+           shard_ctx: Optional[ShardCtx], stale=None):
+    """The body of both rounds (see :func:`wssl_round`).
+
+    ``stale`` is None for the synchronous round.  For the bounded-staleness
+    round (``core/async_round.py``) it is ``(astate, async_p)``, and
+    the body adds admission (:func:`_admit`), delivery (:func:`_deliver`)
+    and parking (:func:`_park`), which read ``AsyncState`` and
+    ``AsyncParams`` by field.  Every async op sits under ``stale is not
+    None``, so the synchronous round traces none of them.  Returns
+    ``(WSSLState, RoundMetrics, out)``: ``out`` is None, or ``(pending,
+    staleness, buffer, fields)`` with ``fields`` the
+    ``AsyncRoundMetrics`` counters."""
+    ctx = shard_ctx
+    n = wssl_cfg.num_clients
+    n_loc = n // ctx.num_shards if ctx is not None else n
+    num_edges = len(state.edge_stages)
+    rng, rng_sel = jax.random.split(state.rng)
+    comp_cfg = wssl_cfg.compression
+    if comp_cfg.enabled and comp_p is None:
+        comp_p = compress_mod.compression_params(comp_cfg)
+    # activation-path compression (CompressionConfig.activations): hop
+    # crossings ship a lossy wire reconstruction; off = nothing traced
+    compress_acts = comp_cfg.enabled and comp_cfg.activations
+    if stale is not None:
+        astate, async_p = stale
+
+    # ---- fault injection (repro.sim): sampled first so the latency
+    # signal can reach the selection draw; the fold_in stream keeps the
+    # Gumbel draw untouched -----------------------------------------------
+    plan = None
+    if scenario is not None:
+        plan = sim_faults.sample_fault_plan(
+            jax.random.fold_in(rng_sel, 0x0DD), scenario, n,
+            num_hops=num_edges, hop_replicas=wssl_cfg.hop_replicas)
+
+    # ---- Algorithm 1: selection (round 0 selects everyone — the rule
+    # lives in wssl.participation_mask).  With select_staleness_beta > 0
+    # slow clients, and in the async round busy ones (pending rounds), pay
+    # a penalty at the draw itself. --------------------------------------
+    penalty = None
+    if wssl_cfg.select_staleness_beta and (plan is not None
+                                           or stale is not None):
+        penalty = sim_faults.client_latencies(plan, n) - 1.0
+        if stale is not None:
+            penalty = penalty + astate.pending.astype(jnp.float32)
+    # the k indices are drawn once: they pick the rows the split fwd/bwd
+    # runs on, and the mask is made from them
+    idx = wssl.weighted_sample(rng_sel, state.importance,
+                               wssl_cfg.num_selected(), penalty=penalty,
+                               beta=wssl_cfg.select_staleness_beta)
+    mask = wssl.participation_mask(rng_sel, state.importance, wssl_cfg,
+                                   state.round_index, idx=idx)
+
+    # dropout ⇒ zero-mask (dropped clients compose like unselected ones)
+    if plan is not None:
+        mask = mask * plan.keep
+
+    # part: the clients whose fresh work this round steps and counts — the
+    # mask, or in the async round the on-time and admitted late clients
+    part, adm = mask, None
+    if stale is not None:
+        adm = _admit(mask, plan, astate, async_p, n)
+        part = adm["part"]
+    agg_w = wssl.aggregation_weights(state.importance, part, wssl_cfg)
+
+    # local views for the per-client tensor work: everything above (plan,
+    # part, agg_w) is a full replicated (N,) decision vector; below, the
+    # shard only touches its own N/S client rows.  All are the originals
+    # when flat.
+    plan_loc = _local_plan(plan, ctx, n_loc)
+    part_loc = _loc(part, ctx, n_loc)
+    agg_w_loc = _loc(agg_w, ctx, n_loc)
+    if stale is not None:
+        # the local views _deliver and _park read
+        adm.update(arriving_loc=_loc(adm["arriving"], ctx, n_loc),
+                   admit_loc=_loc(adm["admit"], ctx, n_loc),
+                   pending_loc=_loc(astate.pending, ctx, n_loc))
+
+    tokens = shard_activation(batch["tokens"], "client", None, None)
+    labels = shard_activation(batch["labels"], "client", None, None)
+    if plan is not None:
+        labels = sim_faults.corrupt_labels(plan_loc, labels,
+                                           model_cfg.vocab_size)
+    embeds = batch.get("embeds")
+
+    # ---- Algorithm 2 steps 2-4: split fwd / chained N-phase backward ----
+    (loss, pcl, g_client, g_server, g_edges, hop_bytes, act_wire_bytes,
+     work_rows) = _split_grads(
+        state, tokens, labels, embeds, agg_w_loc * part_loc, idx,
+        model_cfg=model_cfg, wssl_cfg=wssl_cfg, train_cfg=train_cfg,
+        impl=impl, n=n, n_loc=n_loc, ctx=ctx, comp_cfg=comp_cfg,
+        comp_p=comp_p, compress_acts=compress_acts, rng_sel=rng_sel)
+
+    # ---- optimizer: clip, step (masked for unselected clients), faults ---
+    with jax.named_scope("wssl.optimizer"):
+        if train_cfg.grad_clip:
+            g_client, _ = clip_by_global_norm(
+                g_client, train_cfg.grad_clip,
+                axis_name=ctx.axis if ctx is not None else None)
+            g_server, _ = clip_by_global_norm(g_server, train_cfg.grad_clip)
+            g_edges = [clip_by_global_norm(g, train_cfg.grad_clip)[0]
+                       for g in g_edges]
+
+        if plan is not None:
+            # adversarial corruption models the *sent* client update, so
+            # it applies after the shared global-norm clip — otherwise one
+            # adversary's noise inflates the joint norm and attenuates
+            # every clean client's gradient through the clip factor
+            g_client = sim_faults.corrupt_client_grads(
+                plan_loc, g_client,
+                jax.random.fold_in(rng_sel, 0xBAD) if ctx is None
+                else jax.random.fold_in(jax.random.fold_in(rng_sel, 0xBAD),
+                                        ctx.index))
+
+        _, opt_update = make_optimizer(train_cfg.optimizer)
+        okw = _opt_kwargs(train_cfg)
+        lr = schedule(state.round_index)
+        new_cstack, new_opt_c = opt_update(
+            state.client_stack, g_client, state.opt_client, lr=lr,
+            weight_decay=train_cfg.weight_decay, mask=part_loc, **okw)
+        new_server, new_opt_s = opt_update(
+            state.server_params, g_server, state.opt_server, lr=lr,
+            weight_decay=train_cfg.weight_decay, **okw)
+        new_edges, new_opt_e = [], []
+        for ep, ge, oe in zip(state.edge_stages, g_edges, state.opt_edge):
+            ne, no = opt_update(ep, ge, oe, lr=lr,
+                                weight_decay=train_cfg.weight_decay, **okw)
+            new_edges.append(ne)
+            new_opt_e.append(no)
+        if plan is not None:
+            # straggler / slow-hop partial progress and Byzantine
+            # amplification on the post-optimizer update (a constant
+            # gradient scale would be inert under Adam).  With a finite
+            # deadline the async round models latency as *when* the update
+            # lands, not how much of it: the partial-progress scale is
+            # neutralised, Byzantine amplification still applies.
+            step_plan = plan_loc
+            if stale is not None:
+                step_plan = plan_loc._replace(grad_scale=jnp.where(
+                    jnp.isinf(async_p.deadline), plan_loc.grad_scale,
+                    jnp.ones_like(plan_loc.grad_scale)))
+            new_cstack = sim_faults.scale_client_updates(
+                step_plan, new_cstack, state.client_stack)
+            # adaptive adversaries craft their sent stage from the round's
+            # honest updates (mean − z·std) — inside the honest spread, so
+            # importance down-weighting cannot catch them
+            new_cstack = sim_faults.adaptive_scale_updates(
+                plan_loc, new_cstack, state.client_stack, part_loc,
+                axis_name=ctx.axis if ctx is not None else None)
+        if plan is not None or stale is not None:
+            # a round emptied by dropout or a deadline leaves the shared
+            # stages untouched too: the aux term and weight decay would
+            # still step (and decay) them (an identity at deadline = inf)
+            alive = part.sum() > 0
+            keep_old = lambda new, old: jax.tree.map(
+                lambda a, b: jnp.where(alive, a, b), new, old)
+            new_server = keep_old(new_server, state.server_params)
+            new_opt_s = keep_old(new_opt_s, state.opt_server)
+            new_edges = [keep_old(ne, oe)
+                         for ne, oe in zip(new_edges, state.edge_stages)]
+            new_opt_e = [keep_old(no, oo)
+                         for no, oo in zip(new_opt_e, state.opt_edge)]
+        new_edges = tuple(new_edges)
+        new_opt_e = tuple(new_opt_e)
+
+    # ---- validation on the server-held ζ → importance ------------------
+    with jax.named_scope("wssl.validate"):
+        val_losses, val_rows = _validate(
+            new_cstack, new_edges, new_server, val_batch, idx,
+            state.round_index, model_cfg=model_cfg, train_cfg=train_cfg,
+            impl=impl, n=n, ctx=ctx, faults=plan is not None)
+        importance = (state.importance if val_batch is None else
+                      wssl.compute_importance(val_losses, wssl_cfg,
+                                              prev=state.importance))
+
+    with jax.named_scope("wssl.aggregate"):
+        # the async round aggregates the stale updates that land, too
+        agg_stack, weights, weights_loc = new_cstack, part, part_loc
+        if stale is not None:
+            agg_stack, weights, weights_loc = _deliver(
+                new_cstack, state.client_stack, astate, adm, async_p,
+                wssl_cfg.async_rounds.staleness_weighting, ctx, n_loc)
+
+        # ---- update-path compression (repro.compress): the *sent* delta
+        # (a stale one the round it lands) is compressed client-side; every
+        # registry rule runs on old + decompress(compress(Δ + e)).  With
+        # scheme="none" this whole block is absent from the trace.
+        ef_residual = state.ef_residual
+        if comp_cfg.enabled:
+            delta = jax.tree.map(lambda a, b: a.astype(jnp.float32)
+                                 - b.astype(jnp.float32),
+                                 agg_stack, state.client_stack)
+            rng_comp = jax.random.fold_in(rng_sel, 0xC09)
+            if ctx is not None:
+                # decorrelate the per-coordinate stochastic draws across
+                # shards (the flat round draws one (N, m) tensor per leaf;
+                # per-shard draws necessarily differ — documented tolerance)
+                rng_comp = jax.random.fold_in(rng_comp, ctx.index)
+            sent, ef_residual = compress_mod.apply_compression(
+                delta, ef_residual, weights_loc, rng_comp, comp_cfg, comp_p)
+            agg_stack = jax.tree.map(
+                lambda old, s: (old.astype(jnp.float32)
+                                + s).astype(old.dtype),
+                state.client_stack, sent)
+
+        # ---- Algorithm 2 step 5: registry-dispatched aggregation + sync.
+        # Dropout or a deadline can empty the round; `safe` falls back to a
+        # no-op sync.  Robust rules binarize a fractional stale weight.
+        safe = plan is not None or stale is not None
+        if ctx is None:
+            global_client = aggregation.aggregate_clients(
+                agg_stack, importance, weights, wssl_cfg, safe=safe,
+                params=agg_p)
+        else:
+            # two-level tree: per-shard partial aggregate, psum combine (or
+            # the documented all_gather fallback for non-decomposable rules)
+            global_client = aggregation.shard_aggregate_clients(
+                agg_stack, importance, weights, wssl_cfg, axis_name=ctx.axis,
+                shard_index=ctx.index, num_shards=ctx.num_shards,
+                safe=safe, params=agg_p)
+        presync_cstack = new_cstack
+        new_cstack = wssl.broadcast_global(new_cstack, global_client)
+
+    if stale is not None:
+        parked = _park(presync_cstack, state.client_stack, astate, adm)
+
+    # ---- communication accounting --------------------------------------
+    # the async round uploads its on-time updates and the stale ones that
+    # land, and resyncs the evicted clients (inside bytes_sync)
+    sel = part.sum()
+    if stale is not None:
+        n_arrived, n_evicted = adm["arriving"].sum(), adm["evicted"].sum()
+    bytes_per_hop = sel * jnp.asarray(hop_bytes, jnp.float32)
+    stage_bytes = jnp.asarray(_client_stage_bytes(state.client_stack),
+                              jnp.float32)
+    uploads = sel
+    if stale is not None:
+        bytes_resync = n_evicted * stage_bytes
+        uploads = adm["on_time"].sum() + n_arrived
+    update_raw = uploads * stage_bytes
+    if comp_cfg.enabled:
+        comp_stage = compress_mod.compressed_stage_bytes(
+            state.client_stack, comp_cfg, comp_p)
+        update_comp = uploads * comp_stage
+        # sync = compressed upload from the selected + raw broadcast to all
+        bytes_sync = uploads * comp_stage + n * stage_bytes
+    else:
+        update_comp = update_raw
+        bytes_sync = sync_round_bytes(uploads, n, stage_bytes)
+    if stale is not None:
+        bytes_sync = bytes_sync + bytes_resync
+    if ctx is not None:
+        cross, intra = hierarchical_sync_bytes(
+            uploads, n, ctx.num_shards, stage_bytes,
+            aggregation.rule_decomposes(wssl_cfg))
+    else:
+        cross = intra = jnp.zeros((), jnp.float32)
+    if compress_acts:
+        act_raw = sel * 2.0 * jnp.asarray(hop_bytes, jnp.float32).sum()
+        act_comp = sel * 2.0 * sum(act_wire_bytes)
+    else:
+        act_raw = act_comp = jnp.zeros((), jnp.float32)
+    metrics = RoundMetrics(
+        loss=loss, per_client_loss=_gather(pcl, ctx) * part,
+        val_loss=val_losses,
+        mask=part, importance=importance,
+        bytes_up=bytes_per_hop.sum(), bytes_down=bytes_per_hop.sum(),
+        bytes_per_hop=bytes_per_hop,
+        bytes_sync=bytes_sync,
+        bytes_update_raw=update_raw,
+        bytes_update_comp=update_comp,
+        bytes_cross_shard=cross, bytes_intra_shard=intra,
+        bytes_act_raw=act_raw, bytes_act_comp=act_comp,
+        work_rows=work_rows, val_rows=val_rows,
+    )
+    out = None
+    if stale is not None:
+        out = (*parked, dict(
+            on_time=adm["on_time"].sum(), buffered=adm["admit"].sum(),
+            arrived=n_arrived, evicted=n_evicted,
+            mean_staleness=((adm["arriving"] * astate.staleness).sum()
+                            / jnp.maximum(n_arrived, 1.0)),
+            bytes_resync=bytes_resync))
+    new_state = WSSLState(
+        client_stack=new_cstack, server_params=new_server,
+        edge_stages=new_edges, opt_client=new_opt_c, opt_server=new_opt_s,
+        opt_edge=new_opt_e, importance=importance,
+        round_index=state.round_index + 1, rng=rng,
+        ef_residual=ef_residual)
+    return new_state, metrics, out
+
+
 def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
                val_batch: Optional[Dict[str, jax.Array]] = None,
                scenario: Optional["sim_faults.ScenarioParams"] = None,
@@ -852,233 +1223,52 @@ def wssl_round(state: WSSLState, batch: Dict[str, jax.Array],
     them; they add no op): ``wssl.client`` (client forward and its
     backward), ``wssl.server`` (server forward, loss and backward),
     ``wssl.optimizer``, ``wssl.validate`` and ``wssl.aggregate``.
-    Selection, the edge relay and the byte accounting are unscoped."""
-    ctx = shard_ctx
-    n = wssl_cfg.num_clients
-    n_loc = n // ctx.num_shards if ctx is not None else n
-    num_edges = len(state.edge_stages)
-    rng, rng_sel = jax.random.split(state.rng)
-    comp_cfg = wssl_cfg.compression
-    if comp_cfg.enabled and comp_p is None:
-        comp_p = compress_mod.compression_params(comp_cfg)
-    # activation-path compression (CompressionConfig.activations): hop
-    # crossings ship a lossy wire reconstruction; off = nothing traced
-    compress_acts = comp_cfg.enabled and comp_cfg.activations
+    Selection, the edge relay and the byte accounting are unscoped.
 
-    # ---- fault injection (repro.sim): sampled first so the latency
-    # signal can reach the selection draw; the fold_in stream keeps the
-    # Gumbel draw untouched -----------------------------------------------
-    plan = None
-    if scenario is not None:
-        plan = sim_faults.sample_fault_plan(
-            jax.random.fold_in(rng_sel, 0x0DD), scenario, n,
-            num_hops=num_edges, hop_replicas=wssl_cfg.hop_replicas)
+    Its body (``_round``) is also the bounded-staleness round's
+    (``core/async_round.py``), whose steps it traces for an AsyncState."""
+    return _round(state, batch, val_batch, scenario, agg_p, comp_p,
+                  model_cfg=model_cfg, wssl_cfg=wssl_cfg, train_cfg=train_cfg,
+                  schedule=schedule, impl=impl, shard_ctx=shard_ctx)[:2]
 
-    # ---- Algorithm 1: selection (round 0 selects everyone — the rule
-    # lives in wssl.participation_mask).  With select_staleness_beta > 0
-    # slow clients pay a latency penalty at the draw itself. -------------
-    penalty = None
-    if wssl_cfg.select_staleness_beta and plan is not None:
-        penalty = sim_faults.client_latencies(plan, n) - 1.0
-    # the k indices are drawn once: they pick the rows the split fwd/bwd
-    # runs on, and the mask is made from them
-    idx = wssl.weighted_sample(rng_sel, state.importance,
-                               wssl_cfg.num_selected(), penalty=penalty,
-                               beta=wssl_cfg.select_staleness_beta)
-    mask = wssl.participation_mask(rng_sel, state.importance, wssl_cfg,
-                                   state.round_index, idx=idx)
 
-    # dropout ⇒ zero-mask (dropped clients compose like unselected ones)
-    if plan is not None:
-        mask = mask * plan.keep
+def _round_args(round_body):
+    """``round_body``'s positional parameters, the round's arguments (the
+    keyword-only ones are its static configs)."""
+    return [p for p in inspect.signature(round_body).parameters.values()
+            if p.kind is p.POSITIONAL_OR_KEYWORD]
 
-    agg_w = wssl.aggregation_weights(state.importance, mask, wssl_cfg)
 
-    # local views for the per-client tensor work: everything above (plan,
-    # mask, agg_w) is a full replicated (N,) decision vector; below, the
-    # shard only touches its own N/S client rows.  All four are the
-    # originals when flat.
-    plan_loc = _local_plan(plan, ctx, n_loc)
-    mask_loc = _loc(mask, ctx, n_loc)
-    agg_w_loc = _loc(agg_w, ctx, n_loc)
+def _positional(jitted, round_body):
+    """``round_fn`` taking the round's arguments and calling ``jitted``
+    with all of them, defaults filled in: one call signature, so one
+    executable (``cache_size()``, for the one-executable regression)."""
+    sig = inspect.Signature(_round_args(round_body))
 
-    tokens = shard_activation(batch["tokens"], "client", None, None)
-    labels = shard_activation(batch["labels"], "client", None, None)
-    if plan is not None:
-        labels = sim_faults.corrupt_labels(plan_loc, labels,
-                                           model_cfg.vocab_size)
-    embeds = batch.get("embeds")
+    def round_fn(*args, **kwargs):
+        bound = sig.bind(*args, **kwargs)
+        bound.apply_defaults()
+        return jitted(*bound.args)
 
-    # ---- Algorithm 2 steps 2-4: split fwd / chained N-phase backward ----
-    (loss, pcl, g_client, g_server, g_edges, hop_bytes, act_wire_bytes,
-     work_rows) = _split_grads(
-        state, tokens, labels, embeds, agg_w_loc * mask_loc, idx,
-        model_cfg=model_cfg, wssl_cfg=wssl_cfg, train_cfg=train_cfg,
-        impl=impl, n=n, n_loc=n_loc, ctx=ctx, comp_cfg=comp_cfg,
-        comp_p=comp_p, compress_acts=compress_acts, rng_sel=rng_sel)
+    round_fn.__signature__ = sig
+    round_fn.cache_size = lambda: jitted._cache_size()
+    round_fn._jitted = jitted
+    return round_fn
 
-    # ---- optimizer: clip, step (masked for unselected clients), faults ---
-    with jax.named_scope("wssl.optimizer"):
-        if train_cfg.grad_clip:
-            g_client, _ = clip_by_global_norm(
-                g_client, train_cfg.grad_clip,
-                axis_name=ctx.axis if ctx is not None else None)
-            g_server, _ = clip_by_global_norm(g_server, train_cfg.grad_clip)
-            g_edges = [clip_by_global_norm(g, train_cfg.grad_clip)[0]
-                       for g in g_edges]
 
-        if plan is not None:
-            # adversarial corruption models the *sent* client update, so
-            # it applies after the shared global-norm clip — otherwise one
-            # adversary's noise inflates the joint norm and attenuates
-            # every clean client's gradient through the clip factor
-            g_client = sim_faults.corrupt_client_grads(
-                plan_loc, g_client,
-                jax.random.fold_in(rng_sel, 0xBAD) if ctx is None
-                else jax.random.fold_in(jax.random.fold_in(rng_sel, 0xBAD),
-                                        ctx.index))
-
-        _, opt_update = make_optimizer(train_cfg.optimizer)
-        okw = _opt_kwargs(train_cfg)
-        lr = schedule(state.round_index)
-        new_cstack, new_opt_c = opt_update(
-            state.client_stack, g_client, state.opt_client, lr=lr,
-            weight_decay=train_cfg.weight_decay, mask=mask_loc, **okw)
-        new_server, new_opt_s = opt_update(
-            state.server_params, g_server, state.opt_server, lr=lr,
-            weight_decay=train_cfg.weight_decay, **okw)
-        new_edges, new_opt_e = [], []
-        for ep, ge, oe in zip(state.edge_stages, g_edges, state.opt_edge):
-            ne, no = opt_update(ep, ge, oe, lr=lr,
-                                weight_decay=train_cfg.weight_decay, **okw)
-            new_edges.append(ne)
-            new_opt_e.append(no)
-        if plan is not None:
-            # straggler / slow-hop partial progress and Byzantine
-            # amplification on the post-optimizer update (a constant
-            # gradient scale would be inert under Adam)
-            new_cstack = sim_faults.scale_client_updates(
-                plan_loc, new_cstack, state.client_stack)
-            # adaptive adversaries craft their sent stage from the round's
-            # honest updates (mean − z·std) — inside the honest spread, so
-            # importance down-weighting cannot catch them
-            new_cstack = sim_faults.adaptive_scale_updates(
-                plan_loc, new_cstack, state.client_stack, mask_loc,
-                axis_name=ctx.axis if ctx is not None else None)
-            # an all-dropped round must leave the shared stages untouched
-            # too: with no participants the CE term is zero but the aux term
-            # and weight decay would still step (and decay) them every empty
-            # round
-            alive = mask.sum() > 0
-            keep_old = lambda new, old: jax.tree.map(
-                lambda a, b: jnp.where(alive, a, b), new, old)
-            new_server = keep_old(new_server, state.server_params)
-            new_opt_s = keep_old(new_opt_s, state.opt_server)
-            new_edges = [keep_old(ne, oe)
-                         for ne, oe in zip(new_edges, state.edge_stages)]
-            new_opt_e = [keep_old(no, oo)
-                         for no, oo in zip(new_opt_e, state.opt_edge)]
-        new_edges = tuple(new_edges)
-        new_opt_e = tuple(new_opt_e)
-
-    # ---- validation on the server-held ζ → importance ------------------
-    with jax.named_scope("wssl.validate"):
-        val_losses, val_rows = _validate(
-            new_cstack, new_edges, new_server, val_batch, idx,
-            state.round_index, model_cfg=model_cfg, train_cfg=train_cfg,
-            impl=impl, n=n, ctx=ctx, faults=plan is not None)
-        importance = (state.importance if val_batch is None else
-                      wssl.compute_importance(val_losses, wssl_cfg,
-                                              prev=state.importance))
-
-    # ---- update-path compression (repro.compress) -----------------------
-    # the *sent* stage delta is compressed client-side; the server
-    # reconstructs old + decompress(compress(Δ + e)) before aggregation,
-    # so every registry rule runs on the wire-reconstructed updates.  With
-    # scheme="none" this whole block is absent from the trace.
-    with jax.named_scope("wssl.aggregate"):
-        ef_residual = state.ef_residual
-        if comp_cfg.enabled:
-            delta = jax.tree.map(lambda a, b: a.astype(jnp.float32)
-                                 - b.astype(jnp.float32),
-                                 new_cstack, state.client_stack)
-            rng_comp = jax.random.fold_in(rng_sel, 0xC09)
-            if ctx is not None:
-                # decorrelate the per-coordinate stochastic draws across
-                # shards (the flat round draws one (N, m) tensor per leaf;
-                # per-shard draws necessarily differ — documented tolerance)
-                rng_comp = jax.random.fold_in(rng_comp, ctx.index)
-            sent, ef_residual = compress_mod.apply_compression(
-                delta, ef_residual, mask_loc, rng_comp, comp_cfg, comp_p)
-            agg_stack = jax.tree.map(
-                lambda old, s: (old.astype(jnp.float32)
-                                + s).astype(old.dtype),
-                state.client_stack, sent)
-        else:
-            agg_stack = new_cstack
-
-        # ---- Algorithm 2 step 5: registry-dispatched aggregation + sync
-        # (dropout can empty the selection; `safe` falls back to a no-op
-        # sync)
-        if ctx is None:
-            global_client = aggregation.aggregate_clients(
-                agg_stack, importance, mask, wssl_cfg, safe=plan is not None,
-                params=agg_p)
-        else:
-            # two-level tree: per-shard partial aggregate, psum combine (or
-            # the documented all_gather fallback for non-decomposable rules)
-            global_client = aggregation.shard_aggregate_clients(
-                agg_stack, importance, mask, wssl_cfg, axis_name=ctx.axis,
-                shard_index=ctx.index, num_shards=ctx.num_shards,
-                safe=plan is not None, params=agg_p)
-        new_cstack = wssl.broadcast_global(new_cstack, global_client)
-
-    # ---- communication accounting --------------------------------------
-    sel = mask.sum()
-    bytes_per_hop = sel * jnp.asarray(hop_bytes, jnp.float32)
-    stage_bytes = jnp.asarray(_client_stage_bytes(state.client_stack, n),
-                              jnp.float32)
-    update_raw = sel * stage_bytes
-    if comp_cfg.enabled:
-        comp_stage = compress_mod.compressed_stage_bytes(
-            state.client_stack, n, comp_cfg, comp_p)
-        update_comp = sel * comp_stage
-        # sync = compressed upload from the selected + raw broadcast to all
-        bytes_sync = sel * comp_stage + n * stage_bytes
-    else:
-        update_comp = update_raw
-        bytes_sync = sync_round_bytes(sel, n, stage_bytes)
-    if ctx is not None:
-        cross, intra = hierarchical_sync_bytes(
-            sel, n, ctx.num_shards, stage_bytes,
-            aggregation.rule_decomposes(wssl_cfg))
-    else:
-        cross = intra = jnp.zeros((), jnp.float32)
-    if compress_acts:
-        act_raw = sel * 2.0 * jnp.asarray(hop_bytes, jnp.float32).sum()
-        act_comp = sel * 2.0 * sum(act_wire_bytes)
-    else:
-        act_raw = act_comp = jnp.zeros((), jnp.float32)
-    metrics = RoundMetrics(
-        loss=loss, per_client_loss=_gather(pcl, ctx) * mask,
-        val_loss=val_losses,
-        mask=mask, importance=importance,
-        bytes_up=bytes_per_hop.sum(), bytes_down=bytes_per_hop.sum(),
-        bytes_per_hop=bytes_per_hop,
-        bytes_sync=bytes_sync,
-        bytes_update_raw=update_raw,
-        bytes_update_comp=update_comp,
-        bytes_cross_shard=cross, bytes_intra_shard=intra,
-        bytes_act_raw=act_raw, bytes_act_comp=act_comp,
-        work_rows=work_rows, val_rows=val_rows,
-    )
-    new_state = WSSLState(
-        client_stack=new_cstack, server_params=new_server,
-        edge_stages=new_edges, opt_client=new_opt_c, opt_server=new_opt_s,
-        opt_edge=new_opt_e, importance=importance,
-        round_index=state.round_index + 1, rng=rng,
-        ef_residual=ef_residual)
-    return new_state, metrics
+def _make_round_fn(round_body, donate_argnums, model_cfg: ModelConfig,
+                   wssl_cfg: WSSLConfig, train_cfg: TrainConfig, impl: str,
+                   donate: bool):
+    """:func:`make_round_fn` for ``round_body`` (sync or async), donating
+    ``donate_argnums`` (the incoming state(s)) when ``donate``."""
+    schedule = make_schedule(train_cfg.schedule, train_cfg.learning_rate,
+                             train_cfg.warmup_steps, train_cfg.rounds)
+    fn = functools.partial(round_body, model_cfg=model_cfg,
+                           wssl_cfg=wssl_cfg, train_cfg=train_cfg,
+                           schedule=schedule, impl=impl)
+    if not donate:
+        return fn
+    return _positional(jax.jit(fn, donate_argnums=donate_argnums), round_body)
 
 
 def make_round_fn(model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
@@ -1096,23 +1286,8 @@ def make_round_fn(model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
     warning on CPU) — which is why donation is opt-in here rather than a
     flag on the partial.  Exposes ``cache_size()`` for the
     one-executable regression."""
-    from repro.optim.schedule import make_schedule
-    schedule = make_schedule(train_cfg.schedule, train_cfg.learning_rate,
-                             train_cfg.warmup_steps, train_cfg.rounds)
-    fn = functools.partial(wssl_round, model_cfg=model_cfg,
-                           wssl_cfg=wssl_cfg, train_cfg=train_cfg,
-                           schedule=schedule, impl=impl)
-    if not donate:
-        return fn
-    jitted = jax.jit(fn, donate_argnums=(0,))
-
-    def round_fn(state, batch, val_batch=None, scenario=None, agg_p=None,
-                 comp_p=None):
-        return jitted(state, batch, val_batch, scenario, agg_p, comp_p)
-
-    round_fn.cache_size = lambda: jitted._cache_size()
-    round_fn._jitted = jitted
-    return round_fn
+    return _make_round_fn(wssl_round, (0,), model_cfg, wssl_cfg, train_cfg,
+                          impl, donate)
 
 
 def _linear_shard_index(dp, mesh) -> jax.Array:
@@ -1123,6 +1298,73 @@ def _linear_shard_index(dp, mesh) -> jax.Array:
     for a in dp:
         idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
     return idx
+
+
+def _make_sharded_round_fn(round_body, name: str, model_cfg: ModelConfig,
+                           wssl_cfg: WSSLConfig, train_cfg: TrainConfig,
+                           mesh, impl: str, donate: bool, carried=()):
+    """:func:`make_sharded_round_fn` for ``round_body`` (sync or async).
+    ``carried`` holds the specs of the ``AsyncState`` the async round
+    takes and returns after its :class:`WSSLState`, donated with it."""
+    from contextlib import nullcontext
+    from jax.sharding import PartitionSpec
+    from repro import sharding as shardlib
+
+    dp = shardlib.data_axes_of(mesh)
+    if not dp:
+        raise ValueError(f"{name}: mesh has no data axis "
+                         f"(axes: {mesh.axis_names})")
+    num_shards = math.prod(mesh.shape[a] for a in dp)
+    n = wssl_cfg.num_clients
+    if n % num_shards != 0:
+        raise ValueError(
+            f"num_clients={n} must divide evenly over {num_shards} client "
+            f"shards (mesh data axes {dp})")
+    axis = dp if len(dp) > 1 else dp[0]
+    arules = shardlib.auto_rules(mesh) if shardlib.auto_axes_of(mesh) else {}
+    schedule = make_schedule(train_cfg.schedule, train_cfg.learning_rate,
+                             train_cfg.warmup_steps, train_cfg.rounds)
+    _, state_axes = abstract_state(model_cfg, wssl_cfg, train_cfg)
+    st_specs = shardlib.round_state_specs(mesh, state_axes)
+    client_spec = shardlib.client_axis_spec(mesh)
+    rep = PartitionSpec()
+    states = (st_specs, *carried)
+    nargs = len(_round_args(round_body))
+
+    def body(*args):
+        ctx = ShardCtx(axis=axis, num_shards=num_shards,
+                       index=_linear_shard_index(dp, mesh))
+        bind = (shardlib.use_sharding_rules(mesh, arules) if arules
+                else nullcontext())
+        with bind:
+            return round_body(*args, model_cfg=model_cfg, wssl_cfg=wssl_cfg,
+                              train_cfg=train_cfg, schedule=schedule,
+                              impl=impl, shard_ctx=ctx)
+
+    mapped = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=states + (client_spec,) + (rep,) * (nargs - len(states) - 1),
+        out_specs=states + (rep,),
+        check_vma=False, axis_names=frozenset(dp))
+    # donate the incoming state(s) (default on): the new state aliases the
+    # old, so one copy of the sharded per-client stacks, optimizer slots
+    # (and stale-update buffer) is live at peak.  place_state device_puts
+    # a *copy*, so the caller's host-built state survives the first
+    # donated call.
+    jitted = jax.jit(mapped,
+                     donate_argnums=tuple(range(len(states))) if donate
+                     else ())
+    round_fn = _positional(jitted, round_body)
+    # commit inputs to the round's own shardings up front: host-built
+    # (single-device) state/batch otherwise costs one extra copy-in
+    # executable on the first call before the steady-state one takes over
+    round_fn.place_state = lambda state: jax.device_put(
+        state, shardlib.named_shardings_like(mesh, st_specs, state))
+    round_fn.place_batch = lambda batch: jax.device_put(
+        batch, shardlib.named_shardings_like(mesh, client_spec, batch))
+    round_fn.mesh = mesh
+    round_fn.num_shards = num_shards
+    return round_fn
 
 
 def make_sharded_round_fn(model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
@@ -1150,68 +1392,6 @@ def make_sharded_round_fn(model_cfg: ModelConfig, wssl_cfg: WSSLConfig,
     Matches the flat round within fp32 reassociation tolerance
     (tests/test_sharded_round.py; the psum of per-shard partial sums
     reorders the client reduction)."""
-    from contextlib import nullcontext
-    from jax.sharding import PartitionSpec
-    from repro import sharding as shardlib
-    from repro.optim.schedule import make_schedule
-
-    dp = shardlib.data_axes_of(mesh)
-    if not dp:
-        raise ValueError("make_sharded_round_fn: mesh has no data axis "
-                         f"(axes: {mesh.axis_names})")
-    num_shards = 1
-    for a in dp:
-        num_shards *= mesh.shape[a]
-    n = wssl_cfg.num_clients
-    if n % num_shards != 0:
-        raise ValueError(
-            f"num_clients={n} must divide evenly over {num_shards} client "
-            f"shards (mesh data axes {dp})")
-    axis = dp if len(dp) > 1 else dp[0]
-    auto = shardlib.auto_axes_of(mesh)
-    arules = shardlib.auto_rules(mesh) if auto else {}
-    schedule = make_schedule(train_cfg.schedule, train_cfg.learning_rate,
-                             train_cfg.warmup_steps, train_cfg.rounds)
-    _, state_axes = abstract_state(model_cfg, wssl_cfg, train_cfg)
-    st_specs = shardlib.round_state_specs(mesh, state_axes)
-    client_spec = shardlib.client_axis_spec(mesh)
-    rep = PartitionSpec()
-
-    def body(state, batch, val_batch, scenario, agg_p, comp_p):
-        ctx = ShardCtx(axis=axis, num_shards=num_shards,
-                       index=_linear_shard_index(dp, mesh))
-        bind = (shardlib.use_sharding_rules(mesh, arules) if arules
-                else nullcontext())
-        with bind:
-            return wssl_round(state, batch, val_batch, scenario, agg_p,
-                              comp_p, model_cfg=model_cfg,
-                              wssl_cfg=wssl_cfg, train_cfg=train_cfg,
-                              schedule=schedule, impl=impl, shard_ctx=ctx)
-
-    mapped = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(st_specs, client_spec, rep, rep, rep, rep),
-        out_specs=(st_specs, rep),
-        check_vma=False, axis_names=frozenset(dp))
-    # donate the incoming WSSLState (default on): the new state aliases
-    # the old, so one copy of the sharded per-client stacks + optimizer
-    # slots is live at peak.  place_state device_puts a *copy*, so the
-    # caller's host-built state survives the first donated call.
-    jitted = jax.jit(mapped, donate_argnums=(0,) if donate else ())
-
-    def round_fn(state, batch, val_batch=None, scenario=None, agg_p=None,
-                 comp_p=None):
-        return jitted(state, batch, val_batch, scenario, agg_p, comp_p)
-
-    # commit inputs to the round's own shardings up front: host-built
-    # (single-device) state/batch otherwise costs one extra copy-in
-    # executable on the first call before the steady-state one takes over
-    round_fn.place_state = lambda state: jax.device_put(
-        state, shardlib.named_shardings_like(mesh, st_specs, state))
-    round_fn.place_batch = lambda batch: jax.device_put(
-        batch, shardlib.named_shardings_like(mesh, client_spec, batch))
-    round_fn.mesh = mesh
-    round_fn.num_shards = num_shards
-    round_fn.cache_size = lambda: jitted._cache_size()
-    round_fn._jitted = jitted
-    return round_fn
+    return _make_sharded_round_fn(wssl_round, "make_sharded_round_fn",
+                                  model_cfg, wssl_cfg, train_cfg, mesh, impl,
+                                  donate)

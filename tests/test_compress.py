@@ -257,7 +257,7 @@ def test_traced_bytes_match_protocol(scheme):
         lambda l: jax.ShapeDtypeStruct(l.shape[1:], l.dtype), stack)
     cfg = CompressionConfig(scheme=scheme) if scheme != "none" \
         else CompressionConfig()
-    traced = float(C.compressed_stage_bytes(stack, n, cfg))
+    traced = float(C.compressed_stage_bytes(stack, cfg))
     concrete = protocol.compressed_update_bytes(per_client, scheme,
                                                 cfg.rate)
     assert traced == concrete
